@@ -69,19 +69,17 @@ run's span trace (Chrome-trace JSON + per-batch Gantt) and metrics
 snapshot for CI to upload as workflow artifacts.
 
 An eighth **fleet** scenario measures the sharded design fleet end to
-end: N worker *processes* (subprocess sessions over
-`tests/cache_roundtrip_helper.py`), each with a private L1 artifact
-cache and one shared `FileRemoteStore` L2, exploring an island-model
-request (`DesignRequest.islands > 1`) on a device mesh forced to 8
-host devices (`XLA_FLAGS=--xla_force_host_platform_device_count`).
-The cold worker dispatches the ring-migration mesh engine and writes
-the shared tier; every warm worker serves the same artifact with zero
-explorer dispatches (`served_from="artifact_cache_l2"`, promoted into
-its own L1).  Recorded: mesh device count, migration topology/rounds,
+end: N worker sessions in this process, each with a private L1
+artifact cache and one shared `FileRemoteStore` L2, exploring an
+island-model request (`DesignRequest.islands > 1`) on a mesh of the
+process's devices.  The cold worker dispatches the ring-migration mesh
+engine and writes the shared tier; every warm worker serves the same
+artifact with zero explorer dispatches
+(`served_from="artifact_cache_l2"`, promoted into its own L1).
+Recorded: mesh device count, migration topology/rounds,
 per-tier hit/write counters, per-worker wall, and `artifacts_equal`
-against a single-process in-process baseline — the island engine is
-bit-identical across device counts, so the 8-device fleet front must
-equal the 1-device baseline front.
+against a one-device baseline — the island engine is bit-identical
+across device counts, so the fleet front must equal the baseline's.
 
 Compile counts come from the `nsga2.TRACE_COUNTS["run_cell"]` probe and
 the session dispatch counters.  Per-ticket percentiles use
@@ -103,15 +101,14 @@ import os
 import pathlib
 import platform
 import random
-import subprocess
-import sys
 import tempfile
 import threading
 import time
 
 import jax
 
-from repro.api import DesignRequest, DesignSession, Requirements
+from repro.api import (DesignRequest, DesignSession, Requirements,
+                       TieredArtifactCache)
 from repro.core import nsga2
 from repro.telemetry import (ControllerConfig, Telemetry, atomic_write_json,
                              percentile, write_metrics_json)
@@ -150,14 +147,12 @@ BURST_COUNT, BURST_GAP_S, BURST_JITTER_S = 3, 1.5, 0.1
 BURSTY_NARROW_S, BURSTY_WIDE_S = 0.02, 1.0
 BURSTY_SEEDS = 6
 
-# Fleet-scenario knobs: worker process count, islands per request, and
-# the forced host device count the workers' meshes see.  The island
-# engine uses the largest divisor of `islands` that fits the mesh, so
-# FLEET_ISLANDS devices carry the islands on the 8-device workers while
-# the in-process baseline runs the identical request on 1 device.
+# Fleet-scenario knobs: worker session count and islands per request.
+# The island engine uses the largest divisor of `islands` that fits the
+# mesh, so up to FLEET_ISLANDS devices carry the islands while the
+# baseline runs the identical request on 1 device.
 FLEET_WORKERS = 2
 FLEET_ISLANDS = 4
-FLEET_DEVICES = 8
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -465,71 +460,68 @@ def _chaos(requests, baseline, *, timeout_s: float = 900.0) -> dict:
 
 
 def _fleet(smoke: bool) -> dict:
-    """Sharded-fleet scenario: FLEET_WORKERS subprocess sessions, each a
-    private L1 over one shared L2, exploring an island request on a
-    mesh of FLEET_DEVICES forced host devices.  Worker 0 runs cold
-    (mesh explorer dispatch + L2 write); the rest are warm fleet
-    members (zero dispatches, served from the shared tier).  The
-    in-process baseline runs the identical request single-process —
-    the island engine is device-count independent, so every front must
-    be equal."""
+    """Sharded-fleet scenario: FLEET_WORKERS sessions in this process,
+    each a private L1 over one shared `file://` L2, exploring an island
+    request on a mesh of this process's devices.  Worker 0 runs cold
+    (mesh explorer dispatch + L2 write); the rest are warm fleet members
+    (zero dispatches, served from the shared tier).  The baseline runs
+    the identical request on one device — the island engine is
+    device-count independent, so every front must be equal.  Workers
+    share the process (and so the chip) instead of being child
+    processes: a child cannot reach a chip its parent holds.  The
+    cross-process L2 round trip is covered by
+    `tests/test_design_service_async.py`."""
     pop, gens = (48, 8) if smoke else (96, 40)
     req = DesignRequest(array_size=4096, seed=0, pop_size=pop,
                         generations=gens, requirements=REQUIREMENTS,
                         layout=True, islands=FLEET_ISLANDS, migrate_every=5)
     t0 = time.perf_counter()
-    baseline = DesignSession().run(req)
+    baseline = DesignSession(mesh=1).run(req)
     base_wall = time.perf_counter() - t0
-    base_summary = json.loads(json.dumps(baseline.summary()))
 
     tmp = pathlib.Path(tempfile.mkdtemp(prefix="acim-fleet-"))
     remote = f"file://{tmp / 'shared-l2'}"
     reports, walls = [], []
     for w in range(FLEET_WORKERS):
+        session = DesignSession(artifact_cache=TieredArtifactCache(
+            str(tmp / f"worker{w}-l1"), remote))
         t0 = time.perf_counter()
-        r = subprocess.run(
-            [sys.executable,
-             str(REPO_ROOT / "tests" / "cache_roundtrip_helper.py"),
-             str(tmp / f"worker{w}-l1"), req.to_json(), "--remote", remote],
-            capture_output=True, text=True, timeout=900,
-            env={**os.environ, "PYTHONPATH": str(REPO_ROOT / "src"),
-                 "JAX_PLATFORMS": "cpu",
-                 "XLA_FLAGS":
-                     f"--xla_force_host_platform_device_count={FLEET_DEVICES}"})
+        art = session.run(req)
         walls.append(time.perf_counter() - t0)
-        if r.returncode != 0:
-            raise RuntimeError(f"fleet worker {w} failed: {r.stderr[-3000:]}")
-        reports.append(json.loads(r.stdout))
+        reports.append({"session": session, "artifact": art})
+
+    def stat(rep, key):
+        return int(rep["session"].stats[key])
 
     cold, warm = reports[0], reports[1:]
-    tiers = {k: sum(rep["tier_stats"][f"artifact_cache_{k}"]
-                    for rep in reports)
+    cold_prov = cold["artifact"].provenance
+    tiers = {k: sum(stat(rep, f"artifact_cache_{k}") for rep in reports)
              for k in ("l1_hits", "l2_hits", "promotions", "l2_writes")}
     return {
         "n_workers": FLEET_WORKERS,
         "islands": FLEET_ISLANDS,
         "migrate_every": req.migrate_every,
-        "forced_host_devices": cold["mesh"]["n_devices"],
-        "mesh_devices": cold["mesh"]["mesh_devices"],
-        "migration_topology": cold["mesh"]["migration_topology"],
-        "migration_rounds": cold["mesh"]["migration_rounds"],
+        "n_devices": jax.device_count(),
+        "mesh_devices": cold_prov.mesh_devices,
+        "migration_topology": cold_prov.migration_topology,
+        "migration_rounds": cold_prov.migration_rounds,
         "baseline_wall_s": base_wall,
         "baseline_mesh_devices": baseline.provenance.mesh_devices,
         "worker_wall_s": walls,
         "cold_worker": {
-            "served_from": cold["served_from"],
-            "explorer_dispatches": cold["explorer_dispatches"],
-            "l2_writes": cold["tier_stats"]["artifact_cache_l2_writes"]},
+            "served_from": cold_prov.served_from,
+            "explorer_dispatches": stat(cold, "explorer_dispatches"),
+            "l2_writes": stat(cold, "artifact_cache_l2_writes")},
         "warm_workers": [{
-            "served_from": rep["served_from"],
-            "explorer_dispatches": rep["explorer_dispatches"],
-            "layout_dispatches": rep["layout_dispatches"],
-            "l2_hits": rep["tier_stats"]["artifact_cache_l2_hits"],
-            "promotions": rep["tier_stats"]["artifact_cache_promotions"]}
+            "served_from": rep["artifact"].provenance.served_from,
+            "explorer_dispatches": stat(rep, "explorer_dispatches"),
+            "layout_dispatches": stat(rep, "layout_dispatches"),
+            "l2_hits": stat(rep, "artifact_cache_l2_hits"),
+            "promotions": stat(rep, "artifact_cache_promotions")}
             for rep in warm],
         "tier_hits": tiers,
-        "artifacts_equal": all(rep["summary"] == base_summary
-                               for rep in reports),
+        "artifacts_equal": all(rep["artifact"].summary()
+                               == baseline.summary() for rep in reports),
     }
 
 
@@ -733,7 +725,7 @@ def main() -> None:
           f"artifacts_equal={b['adaptive']['artifacts_equal']}")
     fl = result["fleet"]
     print(f"fleet: {fl['n_workers']} workers x {fl['islands']} islands on "
-          f"{fl['mesh_devices']}/{fl['forced_host_devices']} devices "
+          f"{fl['mesh_devices']}/{fl['n_devices']} devices "
           f"({fl['migration_topology']}, {fl['migration_rounds']} rounds): "
           f"cold={fl['worker_wall_s'][0]:.3f}s "
           f"({fl['cold_worker']['served_from']}) warm="
@@ -754,4 +746,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

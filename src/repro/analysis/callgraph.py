@@ -37,7 +37,7 @@ TRACE_WRAPPERS = {
     "jax.value_and_grad", "jax.lax.scan", "jax.lax.map",
     "jax.lax.while_loop", "jax.lax.fori_loop", "jax.lax.cond",
     "jax.experimental.pallas.pallas_call",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 }
 # Also accepted unnormalized (conventional aliases), so fixture modules
 # and unusual import spellings still root correctly.

@@ -27,8 +27,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, scale: float,
 
     def body(j, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k), slice(None)))
+        k = k_ref[pl.ds(j * block_k, block_k), :]
+        v = v_ref[pl.ds(j * block_k, block_k), :]
         s = q @ k.astype(jnp.float32).T                    # (bq, bk)
         if causal:
             q_idx = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)

@@ -27,8 +27,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.maze_route.ref import INF
+
+# Whole grids live in VMEM: about 22 bytes per cell with the pipeline's
+# double buffers, so the default 16 MiB scoped limit stops near 0.7 M
+# cells.  v5e has 128 MiB of VMEM; this admits grids up to ~4 M cells.
+VMEM_LIMIT = 96 * 2 ** 20
 
 
 def _shift(x: jax.Array, dy: int, dx: int) -> jax.Array:
@@ -46,24 +52,25 @@ def _shift(x: jax.Array, dy: int, dx: int) -> jax.Array:
 
 
 def _kernel(occ_ref, seed_ref, dist_ref):
-    occ = occ_ref[0] != 0
-    seed = seed_ref[0] != 0
-    free = jnp.logical_and(jnp.logical_not(occ), jnp.logical_not(seed))
-    dist0 = jnp.where(seed, 0, INF).astype(jnp.int32)
+    # Mosaic cannot carry or reduce i1 planes through the while loop, so
+    # the masks stay int32 and the loop counts changed cells.
+    seed = seed_ref[0].astype(jnp.int32)
+    fixed = occ_ref[0].astype(jnp.int32) | seed      # blocked or a seed
+    dist0 = jnp.where(seed != 0, 0, INF).astype(jnp.int32)
 
     def cond(state):
         _, changed = state
-        return changed
+        return changed > 0
 
     def body(state):
         dist, _ = state
         best = jnp.minimum(
             jnp.minimum(_shift(dist, 1, 0), _shift(dist, -1, 0)),
             jnp.minimum(_shift(dist, 0, 1), _shift(dist, 0, -1))) + 1
-        nxt = jnp.where(free, jnp.minimum(dist, best), dist)
-        return nxt, jnp.any(nxt < dist)
+        nxt = jnp.where(fixed == 0, jnp.minimum(dist, best), dist)
+        return nxt, jnp.sum((nxt < dist).astype(jnp.int32))
 
-    dist, _ = jax.lax.while_loop(cond, body, (dist0, jnp.bool_(True)))
+    dist, _ = jax.lax.while_loop(cond, body, (dist0, jnp.int32(1)))
     dist_ref[0] = dist
 
 
@@ -83,5 +90,6 @@ def wavefront_kernel(occ: jax.Array, seed: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, h, w), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.int32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(occ.astype(jnp.int8), seed.astype(jnp.int8))

@@ -15,7 +15,7 @@ VMEM and reduces over M on the VPU.
 (P, P) f32 matrix to HBM and running the front-peeling loop as repeated
 dense matmuls (the jnp oracle `repro.core.pareto.non_dominated_rank`),
 this kernel builds the dominance matrix 32 dominator rows at a time in
-VMEM, bit-packs each 32-row strip into one uint32 lane vector (a (P/32, P)
+VMEM, bit-packs each 32-row strip into one int32 lane vector (a (P/32, P)
 scratch — 32x smaller than the bool matrix, 128x smaller than f32), and
 peels fronts on-device: per iteration, the still-unranked ("alive") mask
 is packed into per-word masks and the remaining in-degree of every point
@@ -67,44 +67,48 @@ def dominance_matrix_kernel(f_t: jax.Array, *, block: int = 256,
 def _rank_kernel(f_ref, ft_ref, ranks_ref, packed_ref):
     """f_ref (P, M), ft_ref (M, P) — same objectives in both layouts so the
     dominator strip is a sublane slice and the dominated axis stays on
-    lanes.  ranks_ref (1, P) int32 out; packed_ref (P//32, P) uint32
+    lanes.  ranks_ref (1, P) int32 out; packed_ref (P//32, P) int32
     scratch: bit k of packed[w, j] == "point 32w+k dominates point j"."""
     p, m = f_ref.shape
     n_words = p // 32
     ft = ft_ref[...]                                     # (M, P)
-    strip_bit = jax.lax.broadcasted_iota(jnp.uint32, (32, 1), 0)
+    strip_bit = jax.lax.broadcasted_iota(jnp.int32, (32, 1), 0)
 
     def build(wi, carry):
         fi = f_ref[pl.ds(wi * 32, 32), :]                # (32, M) dominators
         le = jnp.all(fi[:, :, None] <= ft[None, :, :], axis=1)   # (32, P)
         lt = jnp.any(fi[:, :, None] < ft[None, :, :], axis=1)
-        dom = (le & lt).astype(jnp.uint32)
+        dom = (le & lt).astype(jnp.int32)
         packed_ref[pl.ds(wi, 1), :] = jnp.sum(dom << strip_bit, axis=0,
                                               keepdims=True)
         return carry
 
     jax.lax.fori_loop(0, n_words, build, 0)
 
-    lane_bit = jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
+    # spread[w, i] = bit (i % 32) when i falls in word w: a lane
+    # reduction of spread * alive packs the alive mask along the
+    # dominator axis, (1, P) -> (W, 1), without a lane->sublane reshape.
+    word = jax.lax.broadcasted_iota(jnp.int32, (n_words, p), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n_words, p), 1)
+    spread = jnp.where((lane >> 5) == word, 1 << (lane & 31), 0)
 
     def cond(state):
-        ranks, _ = state
-        return jnp.any(ranks < 0)
+        _, _, left = state
+        return left > 0
 
     def body(state):
-        ranks, front = state
-        alive = (ranks < 0).astype(jnp.uint32)           # (1, P)
-        # pack the alive mask along the dominator axis: (1, P) -> (W, 1)
-        alive_w = jnp.sum(alive.reshape(n_words, 32) << lane_bit, axis=1,
-                          keepdims=True)
+        ranks, front, _ = state
+        alive = (ranks < 0).astype(jnp.int32)           # (1, P)
+        alive_w = jnp.sum(spread * alive, axis=1, keepdims=True)   # (W, 1)
         masked = packed_ref[...] & alive_w               # (W, P)
-        indeg = jnp.sum(jax.lax.population_count(masked).astype(jnp.int32),
+        indeg = jnp.sum(jax.lax.population_count(masked),
                         axis=0, keepdims=True)           # (1, P)
-        newfront = (ranks < 0) & (indeg == 0)
-        return jnp.where(newfront, front, ranks), front + 1
+        ranks = jnp.where((alive != 0) & (indeg == 0), front, ranks)
+        return ranks, front + 1, jnp.sum((ranks < 0).astype(jnp.int32))
 
     ranks0 = jnp.full((1, p), -1, jnp.int32)
-    ranks, _ = jax.lax.while_loop(cond, body, (ranks0, jnp.int32(0)))
+    ranks, _, _ = jax.lax.while_loop(cond, body,
+                                     (ranks0, jnp.int32(0), jnp.int32(p)))
     ranks_ref[...] = ranks
 
 
@@ -123,7 +127,7 @@ def nds_rank_kernel(f: jax.Array, *, interpret: bool = False) -> jax.Array:
         ],
         out_specs=pl.BlockSpec((1, p), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, p), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((p // 32, p), jnp.uint32)],
+        scratch_shapes=[pltpu.VMEM((p // 32, p), jnp.int32)],
         interpret=interpret,
     )(f, f.T)
     return ranks[0]

@@ -17,12 +17,15 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (tests / elastic re-mesh)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests / elastic re-mesh).  Auto axes: the step
+    builders place arrays with `NamedSharding`s and leave the rest of the
+    partitioning to the compiler."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dp_size(mesh) -> int:

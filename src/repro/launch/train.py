@@ -13,9 +13,8 @@ from __future__ import annotations
 import argparse
 import sys
 
-import jax
-
 from repro.configs import registry as creg
+from repro.launch.mesh import make_mesh
 from repro.runtime.fault_tolerance import PreemptionGuard, run_supervised
 from repro.train.trainer import TrainerConfig, train
 
@@ -38,7 +37,7 @@ def main() -> int:
 
     cfg = creg.reduced(args.arch) if args.reduced else creg.get(args.arch)
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     tcfg = TrainerConfig(seq=args.seq, global_batch=args.batch,
                          total_steps=args.steps, ckpt_every=args.ckpt_every,
                          ckpt_dir=args.ckpt_dir,

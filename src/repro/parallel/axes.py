@@ -62,18 +62,6 @@ def resolve(names: tuple) -> Optional[P]:
     return _spec_from(rules, names)
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable shard_map: `jax.shard_map` (jax >= 0.5, `check_vma`)
-    when present, else `jax.experimental.shard_map` (`check_rep`)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 def logical(x: jax.Array, *names) -> jax.Array:
     """Constrain x's sharding by logical axis names (no-op w/o rules)."""
     ctx = _RULES.get()

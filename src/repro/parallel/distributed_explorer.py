@@ -52,7 +52,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import nsga2, pareto
 from repro.core.constants import CAL28
-from repro.parallel.axes import shard_map
 from repro.runtime.lock_sanitizer import make_lock
 
 DEFAULT_MIGRATE_EVERY = 20
@@ -132,8 +131,10 @@ def _sharded_cells_program(mesh: Mesh, statics: nsga2.EvolveStatics,
     def body(keys, spaces):
         return jax.vmap(cell)(keys, spaces)
 
-    prog = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                             out_specs=(P(axis), P(axis))))
+    prog = jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(axis), P(axis)),
+                                 out_specs=(P(axis), P(axis)),
+                                 check_vma=False))
     with _PROGRAM_LOCK:
         _PROGRAMS[key] = prog
     return prog
@@ -198,10 +199,10 @@ def _island_program(mesh: Mesh, statics: nsga2.EvolveStatics,
             )(evolve_keys[r], genes, objs)
         return genes, objs
 
-    prog = jax.jit(shard_map(
+    prog = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(None, axis), P()),
-        out_specs=(P(axis), P(axis))))
+        out_specs=(P(axis), P(axis)), check_vma=False))
     with _PROGRAM_LOCK:
         _PROGRAMS[key] = prog
     return prog

@@ -24,7 +24,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models.common import act_fn
 from repro.models.mlp import router_probs
-from repro.parallel.axes import shard_map
 
 
 def moe_fwd_a2a(p: dict, x: jax.Array, cfg: ArchConfig, mesh: Mesh, *,
@@ -52,7 +51,7 @@ def moe_fwd_a2a(p: dict, x: jax.Array, cfg: ArchConfig, mesh: Mesh, *,
         P(batch_axis),             # x sharded over batch
     )
 
-    @functools.partial(shard_map, mesh=mesh, check_vma=False,
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=in_specs, out_specs=P(batch_axis))
     def run(experts, router, x):
         bl, sl, _ = x.shape

@@ -22,7 +22,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.axes import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -44,7 +43,7 @@ def pipeline_apply(mesh: Mesh, stage_axis: str,
     pspec = jax.tree.map(lambda _: P(stage_axis), stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh, check_vma=False,
+        jax.shard_map, mesh=mesh, check_vma=False,
         in_specs=(pspec, P()), out_specs=P())
     def run(params, mb):
         my_params = jax.tree.map(lambda a: a[0], params)

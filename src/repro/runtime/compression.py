@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.optim.adamw import dequantize_blockwise, quantize_blockwise
-from repro.parallel.axes import shard_map
 
 PyTree = Any
 
@@ -52,7 +51,7 @@ def cross_pod_allreduce_compressed(grads: PyTree, ef: PyTree, mesh,
     def one(g, e):
         deq, e2 = compress_decompress(g, e, block)
 
-        @partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
                  check_vma=False)
         def psum_pod(x):
             return jax.lax.psum(x, "pod") / npod

@@ -9,14 +9,14 @@ the shared L2) — runs the request, and prints a JSON report the parent
 asserts on.  Single-tier round trip
 (`tests/test_design_service_async.py`, CI smoke): a repeat request is
 served entirely from disk (`explorer_dispatches == 0`,
-`served_from == "artifact_cache"`).  Fleet round trip (same test file
-and `benchmarks/service_bench.py`'s fleet scenario): a second worker
-process with a cold L1 but the first worker's L2 serves with zero
-explorer dispatches and `served_from == "artifact_cache_l2"`.
+`served_from == "artifact_cache"`).  Fleet round trip (same test
+file): a second worker process with a cold L1 but the first worker's
+L2 serves with zero explorer dispatches and
+`served_from == "artifact_cache_l2"`.
 
 The report carries the session's cache/dispatch counters, the
-artifact's mesh provenance (device count, migration topology/rounds —
-the parent records them in `BENCH_service.json`), and the
+artifact's mesh provenance (device count, migration topology/rounds),
+and the
 provenance-free content summary for cross-process equality checks.
 """
 import argparse

@@ -1,16 +1,16 @@
 """End-to-end training convergence on the structured synthetic stream."""
-import jax
 import numpy as np
 import pytest
 
 from repro.configs import registry as creg
+from repro.launch.mesh import make_mesh
 from repro.train.trainer import TrainerConfig, train
 
 
 @pytest.mark.slow
 def test_reduced_lm_learns(tmp_path):
     cfg = creg.reduced("qwen3_8b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tcfg = TrainerConfig(seq=128, global_batch=8, total_steps=60,
                          ckpt_every=1000, ckpt_dir=str(tmp_path), log_every=0)
     res = train(cfg, mesh, tcfg)
@@ -23,7 +23,7 @@ def test_reduced_lm_learns(tmp_path):
 def test_microbatched_matches_full_batch(tmp_path):
     """Gradient accumulation is loss-equivalent to the monolithic batch."""
     cfg = creg.reduced("qwen2_5_3b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     runs = {}
     for mb in (1, 4):
         tcfg = TrainerConfig(seq=64, global_batch=8, total_steps=8,

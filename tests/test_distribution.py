@@ -79,6 +79,7 @@ class TestMultiDeviceEquivalence:
             import jax, jax.numpy as jnp, numpy as np
             from repro.configs import registry as creg
             from repro.launch import steps as steps_mod
+            from repro.launch.mesh import make_mesh
             from repro.data.synthetic import batch_for
             from repro.train.trainer import init_state, TrainerConfig
             cfg = creg.reduced("qwen2_5_3b")
@@ -87,7 +88,7 @@ class TestMultiDeviceEquivalence:
             for shape, axes in [((8, 1), ("data", "model")),
                                 ((2, 4), ("data", "model")),
                                 ((1, 1), ("data", "model"))]:
-                mesh = jax.make_mesh(shape, axes)
+                mesh = make_mesh(shape, axes)
                 ts = steps_mod.make_train_step(cfg, mesh)
                 state = init_state(cfg, tcfg, ts)
                 state = jax.device_put(state, jax.tree.map(

@@ -7,6 +7,7 @@ import pytest
 
 from repro.checkpoint import ckpt
 from repro.configs import registry as creg
+from repro.launch.mesh import make_mesh
 from repro.runtime.fault_tolerance import (FailureInjector, PreemptionGuard,
                                            RESTART_EXIT_CODE,
                                            SimulatedNodeFailure,
@@ -15,7 +16,7 @@ from repro.train.trainer import TrainerConfig, train
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _tcfg(tmp_path, steps=12, ckpt_every=4):
@@ -108,7 +109,7 @@ class TestElasticRemesh:
 
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         ckpt.save(tmp_path, 1, tree)
-        mesh = jax.make_mesh((1,), ("x",))
+        mesh = make_mesh((1,), ("x",))
         sh = {"w": NamedSharding(mesh, P("x", None))}
         out = ckpt.restore(tmp_path, 1, jax.eval_shape(lambda: tree), sh)
         np.testing.assert_array_equal(np.asarray(out["w"]),

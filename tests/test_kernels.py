@@ -177,3 +177,58 @@ class TestMazeRoute:
         np.testing.assert_array_equal(
             d, np.asarray(wavefront_distance_ref(occ, seed)))
         assert (d[np.asarray(seed)] == 0).all()
+
+
+class TestKernelChoiceOnTPU:
+    """With the backend reported as "tpu", the main path takes the
+    compiled Pallas kernels and the device scan engine: no interpret
+    mode and no host engine on a chip.  The kernels are stubbed; only
+    the choice is under test."""
+
+    @pytest.fixture(autouse=True)
+    def _tpu_backend(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def test_traced_wavefront_takes_compiled_kernel(self, monkeypatch):
+        from repro.kernels.maze_route import ops
+        calls = []
+
+        def kernel(occ, seed, *, interpret):
+            calls.append(interpret)
+            return jnp.zeros(occ.shape, jnp.int32)
+
+        monkeypatch.setattr(ops, "wavefront_kernel", kernel)
+        occ = jnp.zeros((5, 9), bool)
+        seed = occ.at[2, 3].set(True)
+        jax.make_jaxpr(wavefront_distance)(occ, seed)
+        assert calls == [False]
+
+    def test_rank_kernel_not_interpreted(self, monkeypatch):
+        from repro.kernels.pareto_dom import ops
+        calls = []
+
+        def kernel(f, *, interpret):
+            calls.append(interpret)
+            return jnp.zeros(f.shape[0], jnp.int32)
+
+        monkeypatch.setattr(ops, "nds_rank_kernel", kernel)
+        non_dominated_rank(jnp.ones((10, 4)))
+        assert calls == [False]
+
+    def test_batched_route_takes_scan_engine(self, monkeypatch):
+        from repro.eda import batched_flow
+        calls = []
+
+        def route_program(occ0, nets, *, capacity, use_kernel):
+            calls.append(use_kernel)
+            zeros = jnp.zeros(occ0.shape[0], jnp.int32)
+            return occ0, zeros, zeros, zeros
+
+        monkeypatch.setattr(batched_flow, "_route_program", route_program)
+        b, n = 2, 3
+        nets = batched_flow.NetBatch(
+            jnp.zeros((b, n, 2), jnp.int32), jnp.zeros((b, n, 2, 2), jnp.int32),
+            jnp.ones((b, n, 2), bool), jnp.ones((b, n), bool))
+        out = batched_flow.batched_route(nets, np.array([640, 512]),
+                                         np.array([320, 384]))
+        assert out.engine == "scan" and calls == [None]

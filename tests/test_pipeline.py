@@ -22,9 +22,10 @@ def test_pipeline_matches_sequential_and_grads():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.parallel.pipeline import pipeline_apply
 
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         S, M, B, D = 4, 8, 2, 16
         key = jax.random.key(0)
         w = 0.3 * jax.random.normal(key, (S, D, D))
