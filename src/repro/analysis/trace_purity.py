@@ -7,7 +7,10 @@ from a traced root (``jax.jit`` / ``vmap`` / ``lax.scan`` /
   * **host-call** — wall clock (``time.*``), host RNG (stdlib
     ``random.*``, ``numpy.random.*``), console / filesystem
     (``print`` / ``input`` / ``breakpoint`` / ``open``), environment
-    (``os.environ`` / ``os.getenv``), device sync (``.item()``, and
+    (``os.environ`` / ``os.getenv``), profiler spans
+    (``jax.profiler.*``, ``repro.telemetry.spans.trace_span``: host
+    annotations that a trace would open once, at trace time), device
+    sync (``.item()``, and
     ``float()`` / ``int()`` wrapped directly around an array-producing
     call) — all of which either crash under a tracer or silently bake a
     trace-time value into the compiled program;
@@ -36,6 +39,7 @@ from repro.analysis.core import Finding, Module, dotted
 _HOST_PREFIXES = (
     "time.", "random.", "numpy.random.", "os.environ", "os.getenv",
     "os.urandom", "os.system", "subprocess.", "socket.",
+    "jax.profiler.", "repro.telemetry.",
 )
 _HOST_BUILTINS = {"print", "input", "breakpoint", "open"}
 # float(jnp.sum(x)) / int(lax.argmax(...)) force a device sync and bake

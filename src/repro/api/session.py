@@ -55,7 +55,6 @@ modules: `repro.eda.batched_flow` is pure compute.
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import functools
 import json
@@ -72,6 +71,7 @@ from repro.core.explorer import ParetoResult
 from repro.api.request import DesignRequest
 from repro.core.acim_spec import MacroSpec
 from repro.eda.batched_flow import BatchedLayoutResult, iter_layout_buckets
+from repro.telemetry.spans import trace_span
 
 
 # Stamped into every serialized artifact; `repro.api.artifact_cache`
@@ -87,7 +87,8 @@ from repro.eda.batched_flow import BatchedLayoutResult, iter_layout_buckets
 #    islands, migration_topology, migration_rounds) and the tiered-
 #    cache `served_from` values ("artifact_cache_l1"/"_l2"); requests
 #    gained the islands/migrate_every genes.
-ARTIFACT_SCHEMA = 5
+# 6: provenance gained `admit_wait_s` (submit -> admission).
+ARTIFACT_SCHEMA = 6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,10 +118,12 @@ class Provenance:
     # "artifact_cache" (the persistent cross-process store)
     served_from: str = "explorer"
     # staged-pipeline facts (zero on the sequential drivers): how long
-    # the request sat in inter-stage queues before its explore batch was
+    # the request waited from submission to admission into a batch,
+    # then in inter-stage queues before its explore batch was
     # picked up / before its layout buckets dispatched (mean over the
     # buckets the request touched), and whether the artifact was
     # produced by the staged pipeline executor at all
+    admit_wait_s: float = 0.0
     explore_wait_s: float = 0.0
     layout_wait_s: float = 0.0
     pipelined: bool = False
@@ -412,7 +415,8 @@ class DesignSession:
         spans (one per coalesced explore dispatch, distillation, layout
         bucket, finalize pass) — the sequential drivers' side of the
         stage Gantt.  A `DesignService` built with telemetry attaches
-        its recorder here automatically."""
+        its recorder here automatically.  With or without one, each of
+        these spans is a `design.session.<name>` profiler annotation."""
         self._programs: dict[tuple, _SweepProgram] = {}
         self._fronts: dict[tuple, ParetoResult] = {}
         self.recorder = recorder
@@ -455,13 +459,11 @@ class DesignSession:
         with self.stats_lock:
             self.stats[key] += n
 
-    def _span(self, name: str, **tags):
-        """A `cat="session"` telemetry span, or a no-op without a
-        recorder — the stage functions stay zero-overhead when tracing
-        is off."""
-        if self.recorder is None:
-            return contextlib.nullcontext()
-        return self.recorder.span(name, cat="session", **tags)
+    def _span(self, name: str, **tags) -> trace_span:
+        """A `cat="session"` span: a `design.session.<name>` profiler
+        annotation, recorded too when a recorder is attached."""
+        return trace_span(name, cat="session", recorder=self.recorder,
+                          **tags)
 
     # -- program cache ---------------------------------------------------
     def program_for(self, request: DesignRequest) -> _SweepProgram:
@@ -595,7 +597,8 @@ class DesignSession:
                     total_s=time.perf_counter() - t0, new_traces=0,
                     explorer_dispatches=0, layout_dispatches=0,
                     front_cache_hit=False, coalesced=1,
-                    explore_wait_s=0.0, layout_wait_s=0.0, pipelined=False,
+                    admit_wait_s=0.0, explore_wait_s=0.0,
+                    layout_wait_s=0.0, pipelined=False,
                     attempts=0, retried_buckets=0, shed_buckets=0,
                     worker_id="", route_engine="", route_rounds=0,
                     route_collisions=0, mesh_devices=0,
@@ -673,9 +676,10 @@ class DesignSession:
             res = self.layout(bucket.specs, coarse=bucket.coarse,
                               capacity=bucket.capacity)
         dt = time.perf_counter() - t0
-        return BucketResult(bucket=bucket,
-                            rows=dict(zip(res.specs, res.metrics_rows())),
-                            elapsed_s=dt,
+        with trace_span("rows", cat="layout", bucket=bucket.key,
+                        specs=len(bucket.specs)):
+            rows = dict(zip(res.specs, res.metrics_rows()))
+        return BucketResult(bucket=bucket, rows=rows, elapsed_s=dt,
                             result=(res if bucket.request is not None
                                     else None),
                             engine=res.routing.engine,

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core import nsga2
 from repro.core.constants import CAL28, CalibConstants
+from repro.telemetry.spans import trace_span
 
 
 @functools.partial(jax.jit, static_argnames=("statics", "n_gens"))
@@ -76,13 +77,15 @@ def explore_cells(cells, *, pop_size: int = 256, generations: int = 80,
             use_pallas_rank=use_pallas_rank)
         program = functools.partial(sweep_program, statics=statics,
                                     n_gens=generations)
-    spaces = stack_spaces([
-        nsga2.space_operands(nsga2.NSGA2Config(array_size=s, cal=cal))
-        for s, _ in cells])
-    keys = jnp.stack([jax.random.key(sd) for _, sd in cells])
-    genes_b, objs_b = program(keys, spaces)
-    genes_b = np.asarray(genes_b)
-    objs_b = np.asarray(objs_b)
+    with trace_span("launch", cat="explore", cells=len(cells)):
+        spaces = stack_spaces([
+            nsga2.space_operands(nsga2.NSGA2Config(array_size=s, cal=cal))
+            for s, _ in cells])
+        keys = jnp.stack([jax.random.key(sd) for _, sd in cells])
+        genes_b, objs_b = program(keys, spaces)
+    with trace_span("fetch", cat="explore", cells=len(cells)):
+        genes_b = np.asarray(genes_b)
+        objs_b = np.asarray(objs_b)
     return {
         (s, sd): explorer.pareto_result_from_population(
             s, genes_b[i], objs_b[i], cal=cal)

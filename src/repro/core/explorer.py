@@ -35,6 +35,7 @@ import numpy as np
 from repro.core import estimator, nsga2, pareto
 from repro.core.acim_spec import MacroSpec
 from repro.core.constants import CAL28, CalibConstants
+from repro.telemetry.spans import trace_span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,17 +122,21 @@ def _dedup_pareto(genes: np.ndarray, objs: np.ndarray):
 def pareto_result_from_population(array_size: int, genes: np.ndarray,
                                   objs: np.ndarray,
                                   cal: CalibConstants = CAL28) -> ParetoResult:
-    """Distill a final NSGA-II population into a `ParetoResult`."""
-    genes, _ = _dedup_pareto(np.asarray(genes), np.asarray(objs))
-    h = (2 ** genes[:, 0]).astype(np.int64)
-    w = (array_size // h).astype(np.int64)
-    l = (2 ** genes[:, 1]).astype(np.int64)
-    b = genes[:, 2].astype(np.int64)
-    specs = tuple(MacroSpec(int(hh), int(ww), int(ll), int(bb))
-                  for hh, ww, ll, bb in zip(h, w, l, b))
-    rep = estimator.evaluate_report(h.astype(np.float32), w.astype(np.float32),
-                                    l.astype(np.float32), b.astype(np.float32), cal)
-    metrics = {k: np.asarray(v) for k, v in rep.items()}
+    """Distill a final NSGA-II population into a `ParetoResult` (one
+    `design.explore.postprocess` span per cell)."""
+    with trace_span("postprocess", cat="explore", cells=1,
+                    array_size=int(array_size)):
+        genes, _ = _dedup_pareto(np.asarray(genes), np.asarray(objs))
+        h = (2 ** genes[:, 0]).astype(np.int64)
+        w = (array_size // h).astype(np.int64)
+        l = (2 ** genes[:, 1]).astype(np.int64)
+        b = genes[:, 2].astype(np.int64)
+        specs = tuple(MacroSpec(int(hh), int(ww), int(ll), int(bb))
+                      for hh, ww, ll, bb in zip(h, w, l, b))
+        rep = estimator.evaluate_report(
+            h.astype(np.float32), w.astype(np.float32),
+            l.astype(np.float32), b.astype(np.float32), cal)
+        metrics = {k: np.asarray(v) for k, v in rep.items()}
     return ParetoResult(array_size, specs, metrics)
 
 

@@ -62,6 +62,7 @@ from repro.eda.router import NEIGHBORS, grid_shape
 from repro.kernels.maze_route import INF, wavefront_distance
 from repro.kernels.maze_route.frontier import (canvas_free, canvas_index,
                                                expand_buckets, strides)
+from repro.telemetry.spans import trace_span
 
 Array = jax.Array
 
@@ -913,17 +914,23 @@ def generate_layouts(specs, *, coarse: int = 64, capacity: int = 4,
     specs = tuple(specs)
     if not specs:
         raise ValueError("generate_layouts needs at least one MacroSpec")
-    geom = geometry()
-    dims = BatchDims.for_specs(specs)
-    ops = stack_layout_operands(specs, geom)
-    tensors = _place_program(ops, dims=dims, geom=geom)
-    overlaps, oob = _drc_program(tensors, ops, dims=dims, geom=geom)
-    nets = _nets_program(tensors, ops, dims=dims, geom=geom, coarse=coarse)
-    routing = batched_route(nets, np.asarray(ops.width),
-                            np.asarray(ops.height), coarse=coarse,
-                            capacity=capacity, use_kernel=use_kernel,
-                            engine=engine)
-    stats = [nl_mod.stats_for_spec(s) for s in specs]
+    n = len(specs)
+    with trace_span("prepare", cat="layout", specs=n):
+        geom = geometry()
+        dims = BatchDims.for_specs(specs)
+        ops = stack_layout_operands(specs, geom)
+    with trace_span("place_drc_nets", cat="layout", specs=n):
+        tensors = _place_program(ops, dims=dims, geom=geom)
+        overlaps, oob = _drc_program(tensors, ops, dims=dims, geom=geom)
+        nets = _nets_program(tensors, ops, dims=dims, geom=geom,
+                             coarse=coarse)
+    with trace_span("route", cat="layout", specs=n):
+        routing = batched_route(nets, np.asarray(ops.width),
+                                np.asarray(ops.height), coarse=coarse,
+                                capacity=capacity, use_kernel=use_kernel,
+                                engine=engine)
+    with trace_span("netlist_stats", cat="layout", specs=n):
+        stats = [nl_mod.stats_for_spec(s) for s in specs]
     return BatchedLayoutResult(
         specs=specs, dims=dims, geom=geom, ops=ops, tensors=tensors,
         routing=routing, drc_overlaps=np.asarray(overlaps),

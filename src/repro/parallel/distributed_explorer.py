@@ -53,6 +53,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import nsga2, pareto
 from repro.core.constants import CAL28
 from repro.runtime.lock_sanitizer import make_lock
+from repro.telemetry.spans import trace_span
 
 DEFAULT_MIGRATE_EVERY = 20
 MESH_AXIS = "islands"
@@ -255,9 +256,11 @@ def explore_cells_mesh(cells, *, mesh: Mesh | None = None, islands: int = 1,
             lambda *xs: jnp.stack(xs), *(spaces + spaces[:1] * pad))
         keys = jnp.stack([jax.random.key(sd) for _, sd in padded])
         prog = _sharded_cells_program(mesh, statics, generations)
-        genes_b, objs_b = prog(keys, spaces_b)
-        genes_b = np.asarray(genes_b)[:len(cells)]
-        objs_b = np.asarray(objs_b)[:len(cells)]
+        with trace_span("launch", cat="explore", cells=len(cells)):
+            genes_b, objs_b = prog(keys, spaces_b)
+        with trace_span("fetch", cat="explore", cells=len(cells)):
+            genes_b = np.asarray(genes_b)[:len(cells)]
+            objs_b = np.asarray(objs_b)[:len(cells)]
         pops = {cell: (genes_b[i], objs_b[i])
                 for i, cell in enumerate(cells)}
         facts = {"mesh_devices": n_dev, "islands": 1,
@@ -278,9 +281,11 @@ def explore_cells_mesh(cells, *, mesh: Mesh | None = None, islands: int = 1,
         )(jnp.arange(n_rounds))                                     # (R,I,C)
         spaces_b = jax.tree.map(lambda *xs: jnp.stack(xs), *spaces)
         prog = _island_program(sub, statics, schedule, n_elite)
-        genes_b, objs_b = prog(init_keys, evolve_keys, spaces_b)
-        genes_b = np.asarray(genes_b)   # (I, C, P, 3)
-        objs_b = np.asarray(objs_b)
+        with trace_span("launch", cat="explore", cells=len(cells)):
+            genes_b, objs_b = prog(init_keys, evolve_keys, spaces_b)
+        with trace_span("fetch", cat="explore", cells=len(cells)):
+            genes_b = np.asarray(genes_b)   # (I, C, P, 3)
+            objs_b = np.asarray(objs_b)
         pops = {cell: (genes_b[:, i].reshape(-1, genes_b.shape[-1]),
                        objs_b[:, i].reshape(-1, objs_b.shape[-1]))
                 for i, cell in enumerate(cells)}

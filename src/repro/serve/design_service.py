@@ -102,7 +102,10 @@ Accounting: `service.stats()` returns a point-in-time **snapshot** —
 session + service counters (`explorer_dispatches`,
 `layout_dispatches`, `run_cell_traces`, cache hits/misses, the
 `service_batches` / `service_batch_requests` pair whose ratio is the
-realized coalescing factor, and the fault-tolerance counters
+realized coalescing factor, the queue-wait sums `admit_wait_s`
+(submit -> admission, added by the pump as it admits a batch) and
+`explore_wait_s` (admission -> explore start, added by the explore
+stage), and the fault-tolerance counters
 `bucket_retries` / `bucket_failures` / `shed_buckets` / `shed_losses`
 / `stage_worker_restarts` / `preemptions` / `journaled_tickets`) plus
 live pipeline gauges (queue depths, per-stage occupancy and cumulative
@@ -122,7 +125,10 @@ admission pump, every stage-worker unit (the span edges share the
 exact clock reads of the busy clocks), the layout pool, and each
 retry/shed/preemption/replay event — `service.trace()` exports the
 whole run as a Chrome-trace-compatible, schema-stamped event list and
-a per-batch stage Gantt.  With `controller=FeedbackController(...)`
+a per-batch stage Gantt.  Every span, recorder or not, is also a
+`design.<cat>.<name>` profiler annotation (`repro.telemetry.spans
+.trace_span`), so a `jax.profiler` capture names the host work between
+device programs.  With `controller=FeedbackController(...)`
 (or a `ControllerConfig`), the pump additionally runs a feedback tick
 each admission iteration: the arrival-rate EMA eases
 `coalesce_window_s` between the configured bounds, and sustained
@@ -149,7 +155,8 @@ from repro.runtime.fault_tolerance import (FailureInjector, PreemptionGuard,
                                            StragglerMonitor, capped_backoff,
                                            run_supervised)
 from repro.telemetry import (ControllerConfig, FeedbackController,
-                             MetricsRegistry, Telemetry, TraceExport)
+                             MetricsRegistry, Telemetry, TraceExport,
+                             trace_span)
 
 _STAGES = ("explore", "distill", "layout", "finalize")
 
@@ -202,6 +209,14 @@ class _Batch:
         self.completed = set()          # bucket keys with a winning result
         self.shed = set()               # bucket keys re-queued by watchdog
         self.error = None               # batch-level terminal message
+
+
+def _with_admit_wait(art: DesignArtifact, wait: float) -> DesignArtifact:
+    """`art` with this ticket's submit -> admit wait stamped (tickets of
+    one request in one batch share the request's artifact, not its
+    wait)."""
+    return dataclasses.replace(art, provenance=dataclasses.replace(
+        art.provenance, admit_wait_s=wait))
 
 
 class DesignService:
@@ -790,23 +805,22 @@ class DesignService:
         killed, replay still recovers them; drained work is served from
         the artifact cache on replay), stop admitting, and let the
         already-admitted batches run to completion."""
-        drain_span = (None if self.recorder is None
-                      else self.recorder.begin("preempt_drain", cat="fault"))
-        with self._lock:
-            self._preempted = True
-            entries = sorted((e for b in self._inflight for e in b.entries),
-                             key=lambda e: e[0])
-            entries += self._queue   # queued-after-inflight, already ordered
-            self.session.bump("preemptions")
-        n = 0
-        if self.journal is not None and entries:
-            n = self.journal.write([r for _, r, _ in entries])
-        with self._lock:
-            self.session.bump("journaled_tickets", n)
-            self._done_cv.notify_all()   # waiters re-evaluate (PendingTicket)
-        if drain_span is not None:
-            drain_span.args["journaled"] = n
-            self.recorder.end(drain_span)
+        with trace_span("preempt_drain", cat="fault",
+                        recorder=self.recorder) as drain:
+            with self._lock:
+                self._preempted = True
+                entries = sorted((e for b in self._inflight
+                                  for e in b.entries), key=lambda e: e[0])
+                entries += self._queue   # queued-after-inflight, in order
+                self.session.bump("preemptions")
+            n = 0
+            if self.journal is not None and entries:
+                n = self.journal.write([r for _, r, _ in entries])
+            with self._lock:
+                self.session.bump("journaled_tickets", n)
+                self._done_cv.notify_all()   # waiters re-evaluate
+            if drain.span is not None:
+                drain.span.args["journaled"] = n
 
     # -- the staged pipeline ---------------------------------------------
     def _pump_alive(self) -> bool:
@@ -981,6 +995,9 @@ class DesignService:
             batch = _Batch(entries, seq=self._batch_seq)
             self._batch_seq += 1
             self._inflight.append(batch)
+            # each entry's submit -> admit wait ends here
+            self.session.bump("admit_wait_s", sum(
+                batch.admitted_at - t_submit for _, _, t_submit in entries))
             # snapshot under the lock: the controller retunes the window
             # from the pump thread
             window_s = self.coalesce_window_s
@@ -990,34 +1007,37 @@ class DesignService:
                 requests=len(entries),
                 oldest_wait_s=round(batch.admitted_at - entries[0][2], 6),
                 window_s=window_s)
-        self._inject("admit")
-        # blocking put = backpressure: at most `pipeline_depth` batches
-        # queue ahead of the explore stage; never block under the lock
-        self._queues["explore"].put(batch)
+        # the profiler's admit span covers the hand-off, backpressure
+        # included; the recorder keeps its `admit` instant above
+        with trace_span("admit", cat="pump", batch=batch.seq,
+                        requests=len(entries)):
+            self._inject("admit")
+            # blocking put = backpressure: at most `pipeline_depth`
+            # batches queue ahead of the explore stage; never block under
+            # the lock
+            self._queues["explore"].put(batch)
 
     @contextlib.contextmanager
     def _stage(self, name: str, *, batch: int | None = None,
                bucket=None, worker: str | None = None):
-        """Occupancy bookkeeping (and, with a recorder, a `cat="stage"`
-        span) around one unit of stage work.  The span edges share the
-        busy clocks' exact `time.monotonic()` reads, so per-stage span
-        sums and `stage_busy_s` agree to float precision for
-        single-occupant stages — not merely within scheduling jitter."""
+        """Occupancy bookkeeping and a `cat="stage"` span
+        (`design.stage.<name>` on the profiler; recorded too with a
+        recorder) around one unit of stage work.  The recorded span
+        edges share the busy clocks' exact `time.monotonic()` reads, so
+        per-stage span sums and `stage_busy_s` agree to float precision
+        for single-occupant stages — not merely within scheduling
+        jitter."""
         t0 = time.monotonic()
         with self._lock:
             self._mark(name, busy=True, now=t0)
-        span = (None if self.recorder is None
-                else self.recorder.begin(name, cat="stage", batch=batch,
-                                         bucket=bucket, worker=worker,
-                                         at=t0))
-        try:
-            yield
-        finally:
-            t1 = time.monotonic()
-            with self._lock:
-                self._mark(name, busy=False, now=t1)
-            if span is not None:
-                self.recorder.end(span, at=t1)
+        with trace_span(name, cat="stage", recorder=self.recorder, at=t0,
+                        batch=batch, bucket=bucket, worker=worker) as span:
+            try:
+                yield
+            finally:
+                span.end_at = time.monotonic()
+                with self._lock:
+                    self._mark(name, busy=False, now=span.end_at)
 
     def _mark(self, name: str, *, busy: bool,
               now: float | None = None) -> None:
@@ -1197,6 +1217,8 @@ class DesignService:
         start = time.monotonic()
         wait = start - batch.admitted_at
         batch.waits = {r: wait for _, r, _ in batch.entries}
+        # each request's admit -> explore-start wait ends here
+        self.session.bump("explore_wait_s", wait * len(batch.entries))
 
         def call():
             with self._stage("explore", batch=batch.seq):
@@ -1333,7 +1355,8 @@ class DesignService:
                             r, batch.error, pipelined=True,
                             explore_wait_s=batch.waits.get(r, 0.0))
                         for _, r, _ in batch.entries}
-        out = {t: arts[r] for t, r, _ in batch.entries}
+        out = {t: _with_admit_wait(arts[r], batch.admitted_at - t_submit)
+               for t, r, t_submit in batch.entries}
         self._complete(out, batch)
 
     # -- feedback control -------------------------------------------------

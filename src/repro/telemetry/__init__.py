@@ -2,10 +2,11 @@
 
 Three layers, each usable alone:
 
-  * tracing (`repro.telemetry.spans`) — `SpanRecorder` collects
-    monotonic-clock stage spans; `TraceExport` serializes them as a
-    schema-stamped, Chrome-trace-compatible event list and a per-batch
-    stage Gantt;
+  * tracing (`repro.telemetry.spans`) — `trace_span` opens every span
+    as a `design.<cat>.<name>` profiler annotation and, when a
+    `SpanRecorder` is attached, records it there on the monotonic
+    clock; `TraceExport` serializes recorded spans as a schema-stamped,
+    Chrome-trace-compatible event list and a per-batch stage Gantt;
   * metrics (`repro.telemetry.metrics` + `repro.telemetry.export`) —
     a typed `Counter`/`Gauge`/`Histogram` registry snapshotable as
     versioned JSON or prometheus text;
@@ -27,7 +28,8 @@ from repro.telemetry.metrics import (DEFAULT_LATENCY_BUCKETS,
                                      HISTOGRAM_SAMPLE_CAP, METRICS_SCHEMA,
                                      Counter, Gauge, Histogram,
                                      MetricsRegistry, percentile)
-from repro.telemetry.spans import TRACE_SCHEMA, Span, SpanRecorder, TraceExport
+from repro.telemetry.spans import (TRACE_SCHEMA, Span, SpanRecorder,
+                                   TraceExport, trace_span)
 
 
 class Telemetry:
@@ -51,5 +53,5 @@ __all__ = [
     "HISTOGRAM_SAMPLE_CAP", "METRICS_SCHEMA", "MetricsRegistry", "Span",
     "SpanRecorder", "TRACE_SCHEMA", "Telemetry", "TraceExport",
     "atomic_write_json", "load_snapshot", "percentile", "render_prometheus",
-    "write_metrics_json",
+    "trace_span", "write_metrics_json",
 ]

@@ -33,14 +33,29 @@ very same clock reads and agree to float precision
 (`tests/test_telemetry.py`); a K-wide layout pool's busy *clock* is
 the refcounted union while the span *sum* counts worker-seconds, so
 sum >= clock there by construction.
+
+`trace_span` is the one way code opens a span.  It always enters a
+`jax.profiler.TraceAnnotation` named `design.<cat>.<name>` whose
+metadata are the span's tags (`batch`, `bucket`, `worker`, and counts
+such as `requests`, `cells`, `specs`), so a profile captured with
+`jax.profiler.start_trace` names the host work between device programs
+on the profiler's own clock.  A span opened without `batch`/`bucket`/
+`worker` inherits them, in its annotation only, from the innermost
+`trace_span` open on the same thread: a library span under a service
+stage carries the stage's batch.  With a `SpanRecorder` attached the
+span is also recorded there, exactly as `SpanRecorder.begin`/`end`
+record it.  No profile being captured, an annotation is inert: the
+cost is one object per span, and library spans read no Python clock.
+Spans are opened on the host only, never inside traced code.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.lock_sanitizer import make_lock
 
@@ -104,13 +119,8 @@ class SpanRecorder:
             self._spans.append(span)
         return span
 
-    @contextlib.contextmanager
-    def span(self, name: str, **tags):
-        s = self.begin(name, **tags)
-        try:
-            yield s
-        finally:
-            self.end(s)
+    def span(self, name: str, **tags) -> "trace_span":
+        return trace_span(name, recorder=self, **tags)
 
     def instant(self, name: str, *, cat: str = "", batch: int | None = None,
                 bucket=None, worker: str | None = None,
@@ -146,6 +156,56 @@ class SpanRecorder:
                                       args={**s.args, "open": True}))
         spans.sort(key=lambda s: s.start_s)
         return TraceExport(epoch=self.epoch, spans=spans)
+
+
+_open_tags = threading.local()    # per thread: tags of the open spans
+
+
+class trace_span:
+    """One span: a profiler annotation and, with `recorder`, a recorded
+    span (module docstring).  `at` stamps the recorded start; setting
+    `end_at` before the block exits stamps the recorded end, so a caller
+    can share one clock read between its own accounting and the span.
+    `.span` is the recorded `Span` (None without a recorder)."""
+
+    __slots__ = ("_name", "_cat", "_recorder", "_at", "_tags", "_args",
+                 "_ann", "span", "end_at")
+
+    def __init__(self, name: str, *, cat: str = "",
+                 recorder: SpanRecorder | None = None,
+                 at: float | None = None, batch: int | None = None,
+                 bucket=None, worker: str | None = None, **args):
+        self._name, self._cat, self._recorder, self._at = (name, cat,
+                                                           recorder, at)
+        self._tags = {"batch": batch, "bucket": bucket, "worker": worker}
+        self._args = args
+        self.span: Span | None = None
+        self.end_at: float | None = None
+
+    def __enter__(self) -> "trace_span":
+        stack = getattr(_open_tags, "stack", None)
+        if stack is None:
+            stack = _open_tags.stack = []
+        own = {k: None if v is None else str(v) if k == "bucket" else v
+               for k, v in self._tags.items()}
+        tags = ({k: stack[-1][k] if v is None else v for k, v in own.items()}
+                if stack else own)
+        stack.append(tags)
+        self._ann = TraceAnnotation(
+            f"design.{self._cat or 'trace'}.{self._name}",
+            **{k: v for k, v in tags.items() if v is not None}, **self._args)
+        self._ann.__enter__()
+        if self._recorder is not None:
+            self.span = self._recorder.begin(self._name, cat=self._cat,
+                                             at=self._at, **self._tags,
+                                             **self._args)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.span is not None:
+            self._recorder.end(self.span, at=self.end_at)
+        self._ann.__exit__(*exc)
+        _open_tags.stack.pop()
 
 
 @dataclasses.dataclass(frozen=True)
