@@ -496,6 +496,7 @@ class DesignSession:
             n0 = nsga2.TRACE_COUNTS["run_cell"]
             t0 = time.perf_counter()
             facts: dict = {}
+            timings: dict = {}
             if on_mesh:
                 from repro.parallel import distributed_explorer as dx
                 mesh = self._mesh_for_dispatch()
@@ -509,7 +510,8 @@ class DesignSession:
                         crossover_prob=r0.crossover_prob,
                         mutation_prob=r0.mutation_prob, cal=r0.cal,
                         use_pallas_dominance=r0.use_pallas_dominance,
-                        use_pallas_rank=r0.use_pallas_rank)
+                        use_pallas_rank=r0.use_pallas_rank,
+                        timings=timings)
                 self.bump("mesh_dispatches")
             else:
                 prog = self.program_for(r0)
@@ -522,11 +524,12 @@ class DesignSession:
                         mutation_prob=r0.mutation_prob, cal=r0.cal,
                         use_pallas_dominance=r0.use_pallas_dominance,
                         use_pallas_rank=r0.use_pallas_rank,
-                        program=prog.fn)
+                        program=prog.fn, timings=timings)
                 prog.dispatches += 1
             dt = time.perf_counter() - t0
             traces = nsga2.TRACE_COUNTS["run_cell"] - n0
             self.bump("explorer_dispatches")
+            self.bump("explore_host_s", timings.get("host_s", 0.0))
             self.bump("run_cell_traces", traces)
             for cell, front in fronts.items():
                 key = r0.explore_group() + cell

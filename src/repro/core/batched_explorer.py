@@ -22,9 +22,9 @@ Trace accounting: compiling the sweep bumps `nsga2.TRACE_COUNTS
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import nsga2
@@ -40,8 +40,26 @@ def sweep_program(keys, spaces, *, statics: nsga2.EvolveStatics, n_gens: int):
 
 
 def stack_spaces(spaces) -> nsga2.SpaceOperands:
-    """Stack per-cell `SpaceOperands` trees into one batched operand tree."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *spaces)
+    """Stack per-cell `SpaceOperands` trees into one batched host tree."""
+    return jax.tree.map(lambda *xs: np.stack(xs), *spaces)
+
+
+# One program for a batch's keys: each row is `jax.random.key(seed)`.
+_seed_keys = jax.jit(jax.vmap(jax.random.key))
+
+
+def seed_keys(seeds) -> jax.Array:
+    """The stacked `jax.random.key(sd)` of every seed, in one call."""
+    return _seed_keys(np.asarray(seeds, np.int64))
+
+
+def launch_operands(cells, cal: CalibConstants):
+    """(keys, spaces) of a cell list, both on the device: host operand
+    trees memoised per (array size, calibration), stacked on the host and
+    moved in one `device_put`, and the keys made by one program."""
+    spaces = stack_spaces([nsga2.host_space_operands(s, cal)
+                           for s, _ in cells])
+    return seed_keys([sd for _, sd in cells]), jax.device_put(spaces)
 
 
 def explore_cells(cells, *, pop_size: int = 256, generations: int = 80,
@@ -50,7 +68,7 @@ def explore_cells(cells, *, pop_size: int = 256, generations: int = 80,
                   cal: CalibConstants = CAL28,
                   use_pallas_dominance: bool = False,
                   use_pallas_rank: bool = False,
-                  program=None) -> dict:
+                  program=None, timings: dict | None = None) -> dict:
     """Sweep an explicit (array_size, seed) cell list in one device program.
 
     The engine entry point under `repro.api.DesignSession` (which coalesces
@@ -62,7 +80,11 @@ def explore_cells(cells, *, pop_size: int = 256, generations: int = 80,
 
     `program` optionally injects a pre-built sweep callable
     (keys, spaces) -> (genes, objs) — the session's program cache — and
-    defaults to the module-level `sweep_program`.
+    defaults to the module-level `sweep_program`.  `explorer.front_program`
+    follows it on the device; the host then only picks rows per cell.
+
+    `timings`, when given, receives `host_s`: the dispatch's host seconds
+    outside the blocking fetch (launch plus post-processing).
     """
     from repro.core import explorer  # deferred: explorer wraps this module
 
@@ -77,20 +99,37 @@ def explore_cells(cells, *, pop_size: int = 256, generations: int = 80,
             use_pallas_rank=use_pallas_rank)
         program = functools.partial(sweep_program, statics=statics,
                                     n_gens=generations)
+    t0 = time.perf_counter()
     with trace_span("launch", cat="explore", cells=len(cells)):
-        spaces = stack_spaces([
-            nsga2.space_operands(nsga2.NSGA2Config(array_size=s, cal=cal))
-            for s, _ in cells])
-        keys = jnp.stack([jax.random.key(sd) for _, sd in cells])
+        keys, spaces = launch_operands(cells, cal)
         genes_b, objs_b = program(keys, spaces)
+        front_b = explorer.front_program(genes_b, objs_b, spaces, cal=cal)
+    return collect_fronts(cells, (genes_b, objs_b, front_b), cal=cal,
+                          launched_at=t0, timings=timings)
+
+
+def collect_fronts(cells, outputs, *, cal: CalibConstants,
+                   launched_at: float, timings: dict | None) -> dict:
+    """Fetch a dispatch's `outputs` — (genes, objs, `front_program`'s
+    output) with the cells on axis 0 — in one blocking call, and make
+    each cell's `ParetoResult` on the host.  `timings["host_s"]` gets the
+    host seconds from `launched_at` on, the fetch left out."""
+    from repro.core import explorer
+
+    fetch_at = time.perf_counter()
     with trace_span("fetch", cat="explore", cells=len(cells)):
-        genes_b = np.asarray(genes_b)
-        objs_b = np.asarray(objs_b)
-    return {
+        genes_b, objs_b, (mask_b, report_b) = jax.device_get(outputs)
+    fetched_at = time.perf_counter()
+    fronts = {
         (s, sd): explorer.pareto_result_from_population(
-            s, genes_b[i], objs_b[i], cal=cal)
+            s, genes_b[i], objs_b[i], cal=cal, mask=mask_b[i],
+            report=report_b[i])
         for i, (s, sd) in enumerate(cells)
     }
+    if timings is not None:
+        timings["host_s"] = ((fetch_at - launched_at)
+                             + (time.perf_counter() - fetched_at))
+    return fronts
 
 
 def explore_batch(sizes=(4096, 16384, 65536), seeds=(0,), *,

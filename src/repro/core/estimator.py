@@ -106,6 +106,9 @@ def fit_eq11_constants(cal: CalibConstants = CAL28) -> tuple[float, float]:
     report k3 derived analytically from Eq. 5 so that the C0 dependence is
     faithful:  k3 = pref * (E[x^2]*kappa^2 + 2*kT*1e15/Vdd^2) / (sw^2 E[x^2])
     in fF units, then k4 = c + 10log10(k3/C0).
+
+    The fit is host numpy over concrete values, so it also answers from
+    inside a `jit` trace (`evaluate_report` under `explorer.front_program`).
     """
     pref = (2.0 / 3.0) * (1.0 - 4.0 ** (-cal.b_w))
     k3 = pref * (cal.e_x2 * cal.kappa**2 + 2.0 * cal.kt * 1e15 / cal.v_dd**2) / (
@@ -120,7 +123,8 @@ def fit_eq11_constants(cal: CalibConstants = CAL28) -> tuple[float, float]:
     hh = np.array([p[0] for p in pts], np.float32)
     ll = np.array([p[1] for p in pts], np.float32)
     bb = np.array([p[2] for p in pts], np.float32)
-    full = np.asarray(snr_total_db(hh, ll, bb, cal))
+    with jax.ensure_compile_time_eval():
+        full = np.asarray(snr_total_db(hh, ll, bb, cal))
     base = 6.0 * bb - 10.0 * np.log10(hh / ll)
     c = float(np.mean(full - base))
     k4 = c + 10.0 * float(np.log10(k3 / cal.c0_ff))
@@ -281,8 +285,13 @@ def objectives_from_operands(h, w, l, b_adc, ops: CalOperands) -> Array:
     return jnp.stack([-snr_db, -tops, e, a], axis=-1)
 
 
+REPORT_METRICS = ("snr_db", "snr_eq11_db", "tops", "energy_fj_per_mac",
+                  "tops_per_w", "area_f2_per_bit", "cycle_ns")
+
+
 def evaluate_report(h, w, l, b_adc, cal: CalibConstants = CAL28) -> dict:
-    """Human-oriented metrics for one or more design points."""
+    """Human-oriented metrics for one or more design points, keyed in
+    `REPORT_METRICS` order.  Traceable: `cal` is static."""
     return {
         "snr_db": snr_total_db(h, l, b_adc, cal),
         "snr_eq11_db": snr_simplified_db(h, l, b_adc, cal),

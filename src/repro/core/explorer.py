@@ -25,6 +25,7 @@ shims over it, kept for source compatibility.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import warnings
 
@@ -111,32 +112,61 @@ class ParetoResult:
         return cls.from_rows(d["array_size"], d["points"])
 
 
-def _dedup_pareto(genes: np.ndarray, objs: np.ndarray):
-    """Unique genes restricted to the non-dominated set."""
-    uniq, idx = np.unique(genes, axis=0, return_index=True)
-    objs_u = objs[idx]
-    mask = np.asarray(pareto.non_dominated_mask(jnp.asarray(objs_u)))
-    return uniq[mask], objs_u[mask]
+@functools.partial(jax.jit, static_argnames=("cal",))
+def front_program(genes, objs, spaces: nsga2.SpaceOperands, *,
+                  cal: CalibConstants = CAL28):
+    """What the host needs of B final populations, in one program.
+
+    genes (B, N, 3) int32, objs (B, N, 4) float32, `spaces` the stacked
+    operands of the B cells.  Returns `(mask, report)`: mask (B, N) is
+    True where no row of the cell's population dominates the row, and
+    report (B, len(REPORT_METRICS), N) float32 holds the
+    `estimator.evaluate_report` columns of every row.  The shapes follow
+    the sweep's (B, N), never the size of a front; `cal` is static.
+    """
+    mask = jax.vmap(pareto.non_dominated_mask)(objs)
+    h, w, l, b = jax.vmap(nsga2.decode_op)(genes, spaces)
+    rep = estimator.evaluate_report(h, w, l, b, cal)
+    return mask, jnp.stack([rep[k] for k in estimator.REPORT_METRICS], 1)
 
 
 def pareto_result_from_population(array_size: int, genes: np.ndarray,
                                   objs: np.ndarray,
-                                  cal: CalibConstants = CAL28) -> ParetoResult:
+                                  cal: CalibConstants = CAL28, *,
+                                  mask: np.ndarray | None = None,
+                                  report: np.ndarray | None = None
+                                  ) -> ParetoResult:
     """Distill a final NSGA-II population into a `ParetoResult` (one
-    `design.explore.postprocess` span per cell)."""
+    `design.explore.postprocess` span per cell): its distinct genes, in
+    sorted order, that no row of the population dominates.
+
+    `mask` and `report` are this cell's rows of `front_program`'s
+    output; the explorers compute them for the whole batch in one
+    program.  Without them the cell runs `front_program` alone.
+    Identical genes have identical objectives and do not dominate each
+    other, so a gene's first row speaks for all of its rows.
+    """
     with trace_span("postprocess", cat="explore", cells=1,
                     array_size=int(array_size)):
-        genes, _ = _dedup_pareto(np.asarray(genes), np.asarray(objs))
+        genes = np.asarray(genes)
+        if mask is None or report is None:
+            space = jax.tree.map(
+                lambda x: x[None],
+                nsga2.host_space_operands(int(array_size), cal))
+            mask, report = jax.device_get(front_program(
+                genes[None], np.asarray(objs)[None], space, cal=cal))
+            mask, report = mask[0], report[0]
+        uniq, first = np.unique(genes, axis=0, return_index=True)
+        keep = np.asarray(mask)[first]
+        genes, rows = uniq[keep], first[keep]
         h = (2 ** genes[:, 0]).astype(np.int64)
         w = (array_size // h).astype(np.int64)
         l = (2 ** genes[:, 1]).astype(np.int64)
         b = genes[:, 2].astype(np.int64)
         specs = tuple(MacroSpec(int(hh), int(ww), int(ll), int(bb))
                       for hh, ww, ll, bb in zip(h, w, l, b))
-        rep = estimator.evaluate_report(
-            h.astype(np.float32), w.astype(np.float32),
-            l.astype(np.float32), b.astype(np.float32), cal)
-        metrics = {k: np.asarray(v) for k, v in rep.items()}
+        metrics = dict(zip(estimator.REPORT_METRICS,
+                           np.asarray(report)[:, rows]))
     return ParetoResult(array_size, specs, metrics)
 
 

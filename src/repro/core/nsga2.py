@@ -149,6 +149,21 @@ def space_operands(cfg: NSGA2Config) -> SpaceOperands:
     )
 
 
+@functools.lru_cache(maxsize=256)
+def host_space_operands(array_size: int, cal: CalibConstants = CAL28
+                        ) -> SpaceOperands:
+    """`space_operands` as read-only host numpy leaves, built once per
+    (array size, calibration): a dispatch stacks these on the host and
+    hands the batch to the program, with no device work per cell."""
+    def frozen(x):
+        x = np.asarray(x)
+        x.setflags(write=False)
+        return x
+
+    ops = space_operands(NSGA2Config(array_size=array_size, cal=cal))
+    return jax.tree.map(frozen, ops)
+
+
 # ----------------------------------------------------------------------
 # Operand-traced primitives (the vmappable hot path)
 # ----------------------------------------------------------------------

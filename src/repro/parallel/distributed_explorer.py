@@ -43,6 +43,7 @@ migration provenance (device count, topology, rounds) in the artifact.
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +51,8 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core import nsga2, pareto
+from repro.core import batched_explorer as bx
+from repro.core import explorer, nsga2
 from repro.core.constants import CAL28
 from repro.runtime.lock_sanitizer import make_lock
 from repro.telemetry.spans import trace_span
@@ -209,6 +211,31 @@ def _island_program(mesh: Mesh, statics: nsga2.EvolveStatics,
     return prog
 
 
+@functools.partial(jax.jit, static_argnames=("islands", "n_rounds"))
+def _island_keys(base, islands: int, n_rounds: int):
+    """Island i's stream per cell, `fold_in(base, i)` (I, C), and each
+    migration round's keys drawn from it (R, I, C)."""
+    fold = jax.vmap(jax.random.fold_in, in_axes=(0, None))
+    init_keys = jax.vmap(lambda i: fold(base, i))(jnp.arange(islands))
+    evolve_keys = jax.vmap(
+        lambda r: jax.vmap(jax.vmap(
+            lambda k: jax.random.fold_in(k, 0x5EED0000 + r)))(init_keys)
+    )(jnp.arange(n_rounds))
+    return init_keys, evolve_keys
+
+
+@functools.partial(jax.jit, static_argnames=("cal",))
+def _island_front_program(genes, objs, spaces, *, cal):
+    """Merge each cell's island populations, island by island, into one
+    (C, I*P) population and run `explorer.front_program` over it.
+    Returns the merged (genes, objs) and the front program's output."""
+    def merge(x):                    # (I, C, P, k) -> (C, I*P, k)
+        return jnp.moveaxis(x, 0, 1).reshape(x.shape[1], -1, x.shape[-1])
+
+    genes, objs = merge(genes), merge(objs)
+    return genes, objs, explorer.front_program(genes, objs, spaces, cal=cal)
+
+
 # ----------------------------------------------------------------------
 # Entry point
 # ----------------------------------------------------------------------
@@ -218,7 +245,8 @@ def explore_cells_mesh(cells, *, mesh: Mesh | None = None, islands: int = 1,
                        crossover_prob: float = nsga2.DEFAULT_CROSSOVER_PROB,
                        mutation_prob: float = nsga2.DEFAULT_MUTATION_PROB,
                        cal=CAL28, use_pallas_dominance: bool = False,
-                       use_pallas_rank: bool = False):
+                       use_pallas_rank: bool = False,
+                       timings: dict | None = None):
     """Explore an (array_size, seed) cell list over a device mesh.
 
     Returns `({(array_size, seed): ParetoResult}, facts)` — the same
@@ -229,10 +257,11 @@ def explore_cells_mesh(cells, *, mesh: Mesh | None = None, islands: int = 1,
     `islands == 1` shards the cell list (bit-equal per-cell fronts to
     the single-device engine); `islands > 1` runs ring-migrating island
     evolution per cell and merges the union front.  Either way the
-    result is independent of the mesh's device count.
+    result is independent of the mesh's device count.  Both run
+    `explorer.front_program` after the explore program, as
+    `batched_explorer.explore_cells` does; `timings` gets the same
+    `host_s`.
     """
-    from repro.core import explorer  # deferred: explorer wraps core flows
-
     if islands < 1:
         raise ValueError("islands must be >= 1")
     cells = list(dict.fromkeys((int(s), int(sd)) for s, sd in cells))
@@ -245,24 +274,17 @@ def explore_cells_mesh(cells, *, mesh: Mesh | None = None, islands: int = 1,
         mutation_prob=mutation_prob,
         use_pallas_dominance=use_pallas_dominance,
         use_pallas_rank=use_pallas_rank)
-    spaces = [nsga2.space_operands(nsga2.NSGA2Config(array_size=s, cal=cal))
-              for s, _ in cells]
 
+    t0 = time.perf_counter()
     if islands == 1:
         n_dev = mesh_size(mesh)
         pad = (-len(cells)) % n_dev
-        padded = cells + cells[:1] * pad
-        spaces_b = jax.tree.map(
-            lambda *xs: jnp.stack(xs), *(spaces + spaces[:1] * pad))
-        keys = jnp.stack([jax.random.key(sd) for _, sd in padded])
         prog = _sharded_cells_program(mesh, statics, generations)
         with trace_span("launch", cat="explore", cells=len(cells)):
+            keys, spaces_b = bx.launch_operands(cells + cells[:1] * pad, cal)
             genes_b, objs_b = prog(keys, spaces_b)
-        with trace_span("fetch", cat="explore", cells=len(cells)):
-            genes_b = np.asarray(genes_b)[:len(cells)]
-            objs_b = np.asarray(objs_b)[:len(cells)]
-        pops = {cell: (genes_b[i], objs_b[i])
-                for i, cell in enumerate(cells)}
+            front_b = explorer.front_program(genes_b, objs_b, spaces_b,
+                                             cal=cal)
         facts = {"mesh_devices": n_dev, "islands": 1,
                  "migration_topology": "sharded", "migration_rounds": 0}
     else:
@@ -270,39 +292,17 @@ def explore_cells_mesh(cells, *, mesh: Mesh | None = None, islands: int = 1,
         sub = _submesh(mesh, n_dev)
         schedule = _round_schedule(generations, migrate_every)
         n_elite = _elite_count(pop_size)
-        base = jnp.stack([jax.random.key(sd) for _, sd in cells])   # (C,)
-        fold = jax.vmap(jax.random.fold_in, in_axes=(0, None))
-        init_keys = jax.vmap(lambda i: fold(base, i),
-                             out_axes=0)(jnp.arange(islands))       # (I, C)
-        n_rounds = max(len(schedule) - 1, 1)
-        evolve_keys = jax.vmap(
-            lambda r: jax.vmap(jax.vmap(
-                lambda k: jax.random.fold_in(k, 0x5EED0000 + r)))(init_keys)
-        )(jnp.arange(n_rounds))                                     # (R,I,C)
-        spaces_b = jax.tree.map(lambda *xs: jnp.stack(xs), *spaces)
         prog = _island_program(sub, statics, schedule, n_elite)
         with trace_span("launch", cat="explore", cells=len(cells)):
-            genes_b, objs_b = prog(init_keys, evolve_keys, spaces_b)
-        with trace_span("fetch", cat="explore", cells=len(cells)):
-            genes_b = np.asarray(genes_b)   # (I, C, P, 3)
-            objs_b = np.asarray(objs_b)
-        pops = {cell: (genes_b[:, i].reshape(-1, genes_b.shape[-1]),
-                       objs_b[:, i].reshape(-1, objs_b.shape[-1]))
-                for i, cell in enumerate(cells)}
+            base, spaces_b = bx.launch_operands(cells, cal)          # (C,)
+            init_keys, evolve_keys = _island_keys(
+                base, islands, max(len(schedule) - 1, 1))
+            genes_i, objs_i = prog(init_keys, evolve_keys, spaces_b)
+            genes_b, objs_b, front_b = _island_front_program(
+                genes_i, objs_i, spaces_b, cal=cal)
         facts = {"mesh_devices": n_dev, "islands": islands,
                  "migration_topology": "ring",
                  "migration_rounds": len(schedule) - 1}
-
-    fronts = {(s, sd): explorer.pareto_result_from_population(
-                  s, genes, objs, cal=cal)
-              for (s, sd), (genes, objs) in pops.items()}
+    fronts = bx.collect_fronts(cells, (genes_b, objs_b, front_b), cal=cal,
+                               launched_at=t0, timings=timings)
     return fronts, facts
-
-
-def pareto_front_of(genes: np.ndarray, objs: np.ndarray):
-    """Deduplicated non-dominated subset of a raw (genes, objs) union —
-    the test-side distillation of a merged island population."""
-    uniq, idx = np.unique(genes, axis=0, return_index=True)
-    ou = objs[idx]
-    mask = np.asarray(pareto.non_dominated_mask(jnp.asarray(ou)))
-    return uniq[mask], ou[mask]

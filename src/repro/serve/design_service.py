@@ -105,7 +105,9 @@ session + service counters (`explorer_dispatches`,
 realized coalescing factor, the queue-wait sums `admit_wait_s`
 (submit -> admission, added by the pump as it admits a batch) and
 `explore_wait_s` (admission -> explore start, added by the explore
-stage), and the fault-tolerance counters
+stage), the host seconds `explore_host_s` of each explore dispatch
+outside its blocking fetch (launch plus post-processing, added by the
+session), and the fault-tolerance counters
 `bucket_retries` / `bucket_failures` / `shed_buckets` / `shed_losses`
 / `stage_worker_restarts` / `preemptions` / `journaled_tickets`) plus
 live pipeline gauges (queue depths, per-stage occupancy and cumulative

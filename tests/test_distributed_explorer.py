@@ -11,6 +11,7 @@ import jax
 import numpy as np
 import pytest
 
+import front_oracle
 from repro.core import explorer, nsga2, pareto
 from repro.core.batched_explorer import explore_cells
 from repro.parallel import distributed_explorer as dx
@@ -54,6 +55,24 @@ class TestShardedCells:
         # warm re-dispatch: program cache hit, no new trace
         dx.explore_cells_mesh(CELLS, pop_size=40, generations=5)
         assert nsga2.TRACE_COUNTS["run_cell"] - before == 1
+
+
+@pytest.mark.parametrize("islands", [1, 2])
+def test_mesh_front_matches_eager_oracle(islands, monkeypatch):
+    """The mesh engine's front program against the eager front path, on
+    the population each cell hands `pareto_result_from_population` (the
+    island union, island by island, when `islands` > 1)."""
+    pop = 40
+    rec = front_oracle.Recorder(monkeypatch)
+    out, _ = dx.explore_cells_mesh(CELLS, islands=islands, migrate_every=4,
+                                   pop_size=pop, generations=8)
+    assert len(rec.calls) == len(CELLS)
+    for cell, call in zip(CELLS, rec.calls):
+        assert call["result"] is out[cell]
+        assert set(call["kw"]) == {"mask", "report"}
+        assert np.asarray(call["genes"]).shape == (islands * pop, 3)
+        front_oracle.assert_matches(out[cell], cell[0], call["genes"],
+                                    call["objs"])
 
 
 class TestIslands:

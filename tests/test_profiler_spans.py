@@ -7,7 +7,8 @@ without a `SpanRecorder`; library spans inherit `batch`/`bucket` from
 the enclosing span on their thread.  A recorder, when attached, records
 exactly what it recorded before (names, categories, args).  The
 `admit_wait_s`/`explore_wait_s` counters in `stats()` sum the
-per-ticket waits stamped into provenance."""
+per-ticket waits stamped into provenance; `explore_host_s` sums each
+explore dispatch's host seconds outside its fetch."""
 import glob
 import os
 import textwrap
@@ -167,6 +168,43 @@ class TestQueueWaitCounters:
         assert stats["admit_wait_s"] == pytest.approx(sum(admit), abs=1e-9)
         assert stats["explore_wait_s"] == pytest.approx(sum(explore),
                                                         abs=1e-9)
+
+    def test_explore_host_seconds_per_dispatch(self):
+        """`explore_host_s` adds each dispatch's host seconds outside the
+        fetch: above 0, below the dispatches' whole time."""
+        reqs = [_request(seed=s, layout=False) for s in (0, 1, 2)]
+        svc = DesignService(max_coalesce=2, coalesce_window_s=0.02)
+        with svc.serve():
+            arts = [svc.collect(t, timeout=600)
+                    for t in [svc.submit(r) for r in reqs]]
+        stats = svc.stats()
+        assert stats["explorer_dispatches"] >= 2
+        whole = sum(a.provenance.explore_s for a in arts)
+        assert 0.0 < stats["explore_host_s"] < whole
+
+    def test_explore_host_reader(self):
+        import importlib.util
+        import pathlib
+        from types import SimpleNamespace
+
+        path = (pathlib.Path(__file__).resolve().parents[1] / "bench" /
+                "layer_metrics" / "explore_host_ms_per_dispatch.py")
+        spec = importlib.util.spec_from_file_location("lm_explore_host",
+                                                      path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+
+        def ctx(stats, traced=None):
+            win = SimpleNamespace(stats=stats, traced_stats=traced)
+            return {"window": win}
+
+        stats = {"explorer_dispatches": 4, "explore_host_s": 0.02}
+        assert mod.read(ctx(stats)) == pytest.approx(5.0)
+        traced = {"explorer_dispatches": 2, "explore_host_s": 0.004}
+        assert mod.read(ctx(stats, traced)) == pytest.approx(2.0)
+        # a service without the counter, or no dispatch: nothing
+        assert mod.read(ctx({"explorer_dispatches": 4})) is None
+        assert mod.read(ctx({**stats, "explorer_dispatches": 0})) is None
 
     def test_sequential_drivers_stamp_no_wait(self):
         svc = DesignService()
