@@ -679,6 +679,12 @@ class DesignSession:
             res = self.layout(bucket.specs, coarse=bucket.coarse,
                               capacity=bucket.capacity)
         dt = time.perf_counter() - t0
+        routing = res.routing
+        if routing.sweeps is not None:
+            self.bump("route_wavefront_iters", int(routing.sweeps.sum()))
+            self.bump("route_wavefronts",
+                      int((routing.routed + routing.failed).sum()))
+            self.bump("route_goal_stops", int(routing.goal_stops.sum()))
         with trace_span("rows", cat="layout", bucket=bucket.key,
                         specs=len(bucket.specs)):
             rows = dict(zip(res.specs, res.metrics_rows()))

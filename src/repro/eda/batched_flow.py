@@ -59,7 +59,7 @@ from repro.eda.placer import (CATEGORIES, CATEGORY_CELL, BatchDims,
                               PlacerGeometry, category_names, dims_for_spec,
                               geometry, layout_operands, rect_tensors)
 from repro.eda.router import NEIGHBORS, grid_shape
-from repro.kernels.maze_route import INF, wavefront_distance
+from repro.kernels.maze_route import INF, goal_wavefront, wavefront_distance
 from repro.kernels.maze_route.frontier import (canvas_free, canvas_index,
                                                expand_buckets, strides)
 from repro.telemetry.spans import trace_span
@@ -316,7 +316,13 @@ def _route_step(occ_count: Array, hubs: Array, tgts: Array, tmask: Array,
     """Route one net slot across the whole batch.
 
     occ_count: (B, Gh, Gw) int32; hubs (B, 2); tgts (B, 2, 2);
-    tmask (B, 2); nmask (B,).  Returns (occ_count', ok, wirelength).
+    tmask (B, 2); nmask (B,).  Returns (occ_count', ok, wirelength,
+    stats): stats is the Pallas wavefront's (sweeps, goal_stopped) per
+    grid, or None where the jnp ref computes the field.
+
+    The kernel stops each grid's wavefront once the slot's targets are
+    resolved (`goal_wavefront`): the backtrace reads only cells closer
+    than its target, all final by then.  The ref computes whole fields.
     """
     _, gh, gw = occ_count.shape
     occ = occ_count >= capacity
@@ -324,19 +330,22 @@ def _route_step(occ_count: Array, hubs: Array, tgts: Array, tmask: Array,
     ix = jnp.arange(gw)[None, None, :]
     seed = ((iy == hubs[:, 0, None, None]) & (ix == hubs[:, 1, None, None])
             & nmask[:, None, None])
-    # translate the legacy use_kernel knob here: internal code
-    # never calls the deprecated ops spelling (pytest errors on it)
-    impl = None if use_kernel is None else (
-        "kernel" if use_kernel else "ref")
-    dist = wavefront_distance(occ, seed, impl=impl)
+    active = tmask & nmask[:, None]
+    if use_kernel or (use_kernel is None
+                      and jax.default_backend() == "tpu"):
+        goals = jnp.where(active[..., None], tgts, -1)
+        dist, sweeps, stopped = goal_wavefront(occ, seed, goals)
+        stats = (sweeps, stopped)
+    else:
+        dist, stats = wavefront_distance(occ, seed, impl="ref"), None
 
     dirf = jax.vmap(_dir_field)(dist)
     trace = jax.vmap(jax.vmap(_trace_one, in_axes=(None, None, 0, 0)))
-    inc, wl, reach = trace(dist, dirf, tgts, tmask & nmask[:, None])
+    inc, wl, reach = trace(dist, dirf, tgts, active)
     ok = nmask & jnp.all(reach | ~tmask, axis=1)
     occ_count = occ_count + (inc.astype(jnp.int32).sum(axis=1)
                              * ok[:, None, None])
-    return occ_count, ok, wl.sum(axis=1) * ok
+    return occ_count, ok, wl.sum(axis=1) * ok, stats
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "use_kernel"))
@@ -345,21 +354,26 @@ def _route_program(occ0: Array, nets: NetBatch, *, capacity: int,
     """All net slots in one compiled program: `lax.scan` over the slot
     axis with the (occupancy, counters) carry — the sequential
     net-by-net data dependence stays, but there is a single dispatch for
-    the whole batch instead of one per net."""
+    the whole batch instead of one per net.  The last output is the
+    Pallas wavefront's (sweeps, goal stops) per spec, summed over the
+    slots, or None on the ref path."""
 
     def step(carry, slot):
         occ, routed, failed, wirelen = carry
         hubs, tgts, tmask, nmask = slot
-        occ, ok, wl = _route_step(occ, hubs, tgts, tmask, nmask,
-                                  capacity=capacity, use_kernel=use_kernel)
-        return (occ, routed + ok, failed + (nmask & ~ok), wirelen + wl), None
+        occ, ok, wl, stats = _route_step(
+            occ, hubs, tgts, tmask, nmask, capacity=capacity,
+            use_kernel=use_kernel)
+        return ((occ, routed + ok, failed + (nmask & ~ok), wirelen + wl),
+                stats)
 
     bsz = occ0.shape[0]
     zeros = jnp.zeros((bsz,), jnp.int32)
     slots = jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0), nets)
-    (occ, routed, failed, wirelen), _ = jax.lax.scan(
+    (occ, routed, failed, wirelen), stats = jax.lax.scan(
         step, (occ0, zeros, zeros, zeros), slots)
-    return occ, routed, failed, wirelen
+    stats = jax.tree.map(lambda a: a.sum(axis=0), stats)
+    return occ, routed, failed, wirelen, stats
 
 
 # ----------------------------------------------------------------------
@@ -728,6 +742,10 @@ class BatchedRouting(NamedTuple):
     rounds: int = 0             # wavefront dispatch rounds taken
     collisions: int = 0         # buffered routes dropped by a crossing
     schedule: RouteSchedule | None = None
+    # Pallas wavefront counters, summed over the net slots; None where
+    # no kernel ran (the concurrent engine, or the scan over the ref)
+    sweeps: np.ndarray | None = None      # (B,) relaxation sweeps run
+    goal_stops: np.ndarray | None = None  # (B,) stopped on their targets
 
     @property
     def success_rate(self) -> np.ndarray:
@@ -775,13 +793,13 @@ def batched_route(nets: NetBatch, widths: np.ndarray, heights: np.ndarray,
     if engine != "scan":
         raise ValueError(f"engine must be 'scan' or 'concurrent', "
                          f"got {engine!r}")
-    occ, routed, failed, wirelen = _route_program(
-        jnp.asarray(occ0_np), nets, capacity=capacity, use_kernel=use_kernel)
-    occ_np = np.asarray(occ)
-    occ_np = np.where(blocked, 0, occ_np).astype(np.int32)
-    return BatchedRouting(np.asarray(routed), np.asarray(failed),
-                          np.asarray(wirelen), occ_np, grids,
-                          "scan", int(nets.nmask.shape[1]), 0, None)
+    occ, routed, failed, wirelen, stats = jax.device_get(_route_program(
+        jnp.asarray(occ0_np), nets, capacity=capacity, use_kernel=use_kernel))
+    occ_np = np.where(blocked, 0, occ).astype(np.int32)
+    sweeps, goal_stops = stats if stats is not None else (None, None)
+    return BatchedRouting(routed, failed, wirelen, occ_np, grids,
+                          "scan", int(nets.nmask.shape[1]), 0, None,
+                          sweeps, goal_stops)
 
 
 # ----------------------------------------------------------------------

@@ -23,6 +23,12 @@ than silently falling back.  ``use_kernel=True/False`` remains as the
 legacy spelling of impl="kernel"/"ref" (tests force the kernel in
 interpret mode off-TPU and assert it matches the ref).
 
+`goal_wavefront` is the kernel alone, stopped per grid once the given
+goal cells are resolved (the router's targets): the field is then exact
+only out to the goals, which is all a backtrace from them reads.  It
+also returns the sweeps each grid ran.  Full-field callers pass no
+goals and use `wavefront_distance`.
+
 Padding: the kernel needs TPU tile multiples (sublane 8, lane 128).
 `pad_blocked` pads the occupancy with *blocked* cells and the seed with
 zeros — the pad region is masked out of the sweep explicitly, so no
@@ -41,7 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.maze_route.frontier import wavefront_distance_frontier
-from repro.kernels.maze_route.kernel import wavefront_kernel
+from repro.kernels.maze_route.kernel import (goal_wavefront_kernel,
+                                             wavefront_kernel)
 from repro.kernels.maze_route.oracle import wavefront_distance_bfs
 from repro.kernels.maze_route.ref import INF, wavefront_distance_ref
 
@@ -129,3 +136,22 @@ def wavefront_distance(occ: jax.Array, seed: jax.Array, *,
     occ_p, seed_p, (h, w) = pad_blocked(occ, seed)
     out = wavefront_kernel(occ_p, seed_p, interpret=interpret)[:, :h, :w]
     return out[0] if squeeze else out
+
+
+def goal_wavefront(occ: jax.Array, seed: jax.Array, goals: jax.Array, *,
+                   interpret: bool | None = None):
+    """The Pallas wavefront of (B, H, W) grids, each stopped once its
+    goals are resolved (`kernel.goal_wavefront_kernel`).
+
+    goals: (B, K, 2) int32 (y, x) cells; a negative pair is no goal.
+    Returns (dist, sweeps, goal_stopped): int32 distances, exact on every
+    cell whose distance is at most the grid's sweeps and `INF` beyond;
+    (B,) sweeps run; (B,) 1 where the goals stopped the grid before its
+    fixed point.
+    """
+    if interpret is None:
+        interpret = _should_interpret()
+    occ_p, seed_p, (h, w) = pad_blocked(jnp.asarray(occ), jnp.asarray(seed))
+    dist, sweeps, stopped = goal_wavefront_kernel(occ_p, seed_p, goals,
+                                                  interpret=interpret)
+    return dist[:, :h, :w], sweeps, stopped

@@ -270,6 +270,97 @@ class TestConflictScheduler:
             batched_route(nets, w, h, engine="astar")
 
 
+def _multi_grid_nets(per_spec, n_slots):
+    """A NetBatch over several specs from (hub, [targets]) slots each;
+    slots past a spec's own are padding (`nmask` false)."""
+    b = len(per_spec)
+    hubs = np.zeros((b, n_slots, 2), np.int32)
+    tgts = np.zeros((b, n_slots, 2, 2), np.int32)
+    tmask = np.zeros((b, n_slots, 2), bool)
+    nmask = np.zeros((b, n_slots), bool)
+    for i, slots in enumerate(per_spec):
+        one = _grid_nets(slots, 0, 0)
+        n = len(slots)
+        hubs[i, :n], tgts[i, :n] = one.hubs[0], one.tgts[0]
+        tmask[i, :n], nmask[i, :n] = one.tmask[0], True
+    return NetBatch(hubs, tgts, tmask, nmask)
+
+
+def _extent(grids):
+    """(widths, heights) whose coarse=1 routing grids are `grids`."""
+    grids = np.asarray(grids)
+    return grids[:, 1] - 2, grids[:, 0] - 3
+
+
+# spec 0 (6 x 8): slot 0 walls off column 3 at capacity 1, slot 1 then
+# cannot cross it (the fixed-point fallback), slot 2's first target is
+# on the wall (entered from a neighbour); spec 1 (4 x 12, a smaller and
+# wider grid in the same batch): a target on its hub and a padded slot
+WALLS = ([((0, 3), [(5, 3)]), ((2, 0), [(2, 6)]),
+          ((1, 1), [(4, 3), (0, 0)])],
+         [((1, 1), [(1, 6)]), ((2, 2), [(2, 2)])])
+
+
+class TestGoalStoppedScan:
+    """The scan engine over the Pallas kernel stops each wavefront on
+    its net's targets; routing must stay bit-identical to the engines
+    that compute whole fields (the concurrent host engine and the scan
+    over the jnp ref)."""
+
+    def _engines(self, nets, w, h, **kw):
+        kern = batched_route(nets, w, h, engine="scan", use_kernel=True, **kw)
+        ref = batched_route(nets, w, h, engine="scan", use_kernel=False,
+                            **kw)
+        conc = batched_route(nets, w, h, engine="concurrent", **kw)
+        for other in (ref, conc):
+            np.testing.assert_array_equal(kern.routed, other.routed)
+            np.testing.assert_array_equal(kern.failed, other.failed)
+            np.testing.assert_array_equal(kern.wirelength, other.wirelength)
+            np.testing.assert_array_equal(kern.occ_count, other.occ_count)
+        assert ref.sweeps is None and conc.sweeps is None
+        return kern
+
+    @pytest.mark.parametrize("capacity", [4, 1])
+    def test_derived_nets_bit_identical(self, netbatch, capacity):
+        nets, w, h = netbatch
+        kern = self._engines(nets, w, h, capacity=capacity)
+        live = kern.routed + kern.failed
+        assert (kern.goal_stops <= live).all() and kern.goal_stops.sum() > 0
+        if capacity == 1:
+            assert kern.failed.sum() > 0       # congestion bites
+
+    def test_walls_blocked_targets_and_padding_bit_identical(self):
+        nets = _multi_grid_nets(WALLS, 3)
+        w, h = _extent([(6, 8), (4, 12)])
+        kern = self._engines(nets, w, h, coarse=1, capacity=1)
+        assert list(kern.routed) == [2, 2] and list(kern.failed) == [1, 0]
+        # spec 0: the wall's net stops on its target (8 sweeps); the
+        # unreachable net runs to the fixed point of its 6 x 3 side (last
+        # change at sweep 5, found by the test at 8); slot 2's targets
+        # resolve by sweep 5 too, on a field already complete at 8.
+        # spec 1: 8 sweeps and 4, both while the field still grows
+        assert list(kern.sweeps) == [24, 12]
+        assert list(kern.goal_stops) == [1, 2]
+
+    def test_counters_hand_counted(self):
+        # one 4 x 12 grid, capacity 4 (nothing blocks), hub-to-target
+        # distances 5, 0 and 14, one padded slot; a test every 4 sweeps
+        slots = [((1, 1), [(1, 6)]), ((2, 2), [(2, 2)]),
+                 ((0, 0), [(3, 11)])]
+        nets = _multi_grid_nets([slots], 4)
+        nets = NetBatch(nets.hubs[:, [0, 1, 3, 2]], nets.tgts[:, [0, 1, 3, 2]],
+                        nets.tmask[:, [0, 1, 3, 2]],
+                        nets.nmask[:, [0, 1, 3, 2]])
+        w, h = _extent([(4, 12)])
+        kern = self._engines(nets, w, h, coarse=1, capacity=4)
+        # 8 sweeps (goal at 5, field still growing), 4 (goal on the hub),
+        # 0 (no net), 16 (goal at 14 = the far corner: the field's last
+        # change is at sweep 14, so the test at 16 finds the fixed point)
+        assert list(kern.sweeps) == [8 + 4 + 0 + 16]
+        assert list(kern.goal_stops) == [2]
+        assert list(kern.routed + kern.failed) == [3]
+
+
 class TestStillValidBound:
     def test_manhattan_entry(self):
         e = _Buffered(cells=np.zeros(0, np.int64), wl=5, ok=True,

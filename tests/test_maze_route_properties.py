@@ -21,8 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.maze_route import (INF, wavefront_distance,
+from repro.kernels.maze_route import (INF, goal_wavefront, wavefront_distance,
                                       wavefront_distance_bfs)
+from repro.kernels.maze_route.kernel import SWEEPS_PER_CHECK
 from repro.kernels.maze_route.ops import HOST_IMPLS, IMPLS
 
 # The kernel pads to (8, 128) tiles and relaxes the full padded grid per
@@ -204,3 +205,113 @@ class TestDispatchContract:
         with pytest.warns(DeprecationWarning, match="use_kernel"):
             out_kernel = wavefront_distance(occ, seed, use_kernel=True)
         np.testing.assert_array_equal(np.asarray(out_kernel), oracle)
+
+
+def _reach(full, y, x):
+    """The sweep at which a goal is resolved: its distance if free, one
+    past its nearest neighbour's if blocked (`router.target_distance`);
+    `INF` if it cannot be reached."""
+    if full[y, x] < INF:
+        return int(full[y, x])
+    h, w = full.shape
+    nb = [full[y + dy, x + dx] for dy, dx in ((1, 0), (-1, 0), (0, 1),
+                                              (0, -1))
+          if 0 <= y + dy < h and 0 <= x + dx < w]
+    m = min(nb, default=INF)
+    return int(m) + 1 if m < INF else INF
+
+
+def _expected_stop(full, seed, goals):
+    """(sweeps, goal_stopped) the stop rule must give one grid: the first
+    test (every `SWEEPS_PER_CHECK` sweeps) at which every goal is
+    resolved, or at which the block's last sweep changed nothing."""
+    p = SWEEPS_PER_CHECK
+    if not seed.any():
+        return 0, 0
+    top = int(full[full < INF].max())        # the last sweep that changes
+    fixed = p * -(-(top + 1) // p)
+    live = [(y, x) for y, x in goals if y >= 0]
+    last = max((_reach(full, y, x) for y, x in live), default=0)
+    if last >= INF:
+        return fixed, 0
+    at = p * max(1, -(-last // p))
+    return min(at, fixed), int(at < fixed)
+
+
+class TestGoalStop:
+    """`goal_wavefront` stops each grid's Jacobi wavefront once its goal
+    cells are resolved.  By the k-sweep invariant the field is then the
+    BFS field on every cell at distance <= the sweeps run, and `INF` on
+    every other; the sweeps and the stop are exactly what the rule
+    says."""
+
+    def _check(self, occ, seed, goals):
+        full = wavefront_distance_bfs(occ, seed)
+        dist, sweeps, stopped = (np.asarray(a) for a in
+                                 goal_wavefront(occ, seed, goals))
+        for b in range(occ.shape[0]):
+            near = full[b] <= sweeps[b]
+            np.testing.assert_array_equal(dist[b][near], full[b][near])
+            assert (dist[b][~near] == INF).all()
+            assert (sweeps[b], stopped[b]) == _expected_stop(
+                full[b], seed[b], goals[b])
+            for y, x in goals[b]:
+                if y >= 0:       # every goal reads as on the full field
+                    assert _reach(dist[b], y, x) == _reach(full[b], y, x)
+        return full, dist, sweeps, stopped
+
+    @pytest.mark.parametrize("case", range(6))
+    def test_randomized_goals(self, case):
+        rng = np.random.default_rng(2000 + case)
+        b, h, w = 3, int(rng.integers(3, 18)), int(rng.integers(3, 26))
+        occ = rng.random((b, h, w)) < float(rng.uniform(0.0, 0.4))
+        seed = np.zeros((b, h, w), bool)
+        goals = np.full((b, 2, 2), -1, np.int32)
+        for i in range(b):
+            seed[i, rng.integers(h), rng.integers(w)] = True
+            for k in range(2):
+                if k == 0 or rng.random() < 0.7:
+                    goals[i, k] = rng.integers(h), rng.integers(w)
+        self._check(occ, seed, goals)
+
+    def test_blocked_goal_entered_from_final_neighbours(self):
+        # the goal sits on a blocked cell: it resolves when a neighbour
+        # turns finite, and the neighbours at its entry distance are
+        # final then (the backtrace's entry tie-break reads them)
+        occ = np.zeros((1, 7, 9), bool)
+        occ[0, 3, 5] = True
+        seed = np.zeros_like(occ)
+        seed[0, 3, 0] = True
+        goals = np.array([[[3, 5], [-1, -1]]], np.int32)
+        full, dist, sweeps, stopped = self._check(occ, seed, goals)
+        assert full[0, 3, 5] == INF and _reach(full[0], 3, 5) == 5
+        assert (sweeps[0], stopped[0]) == (8, 1)
+        nb = [(4, 5), (2, 5), (3, 6), (3, 4)]      # NEIGHBORS order
+        assert [dist[0][c] for c in nb] == [full[0][c] for c in nb]
+
+    def test_unreachable_goal_runs_to_the_fixed_point(self):
+        occ = np.zeros((2, 6, 9), bool)
+        occ[:, :, 4] = True                        # a full wall
+        seed = np.zeros_like(occ)
+        seed[:, 2, 0] = True
+        goals = np.array([[[2, 7], [-1, -1]],      # beyond the wall
+                          [[2, 2], [3, 8]]], np.int32)
+        full, dist, sweeps, stopped = self._check(occ, seed, goals)
+        np.testing.assert_array_equal(dist, full)
+        assert list(stopped) == [0, 0]
+
+    def test_hand_counted_corridor(self):
+        assert SWEEPS_PER_CHECK == 4       # the counts below assume it
+        occ = np.zeros((4, 2, 10), bool)
+        seed = np.zeros_like(occ)
+        seed[:3, 0, 0] = True              # grid 3 has no seed
+        goals = np.array([[[0, 5], [-1, -1]],    # distance 5: 2 tests
+                          [[0, 0], [-1, -1]],    # on the seed: 1 test
+                          [[1, 9], [0, 9]],      # the far corner, 10:
+                          [[0, 5], [-1, -1]]],   # ...the fixed point
+                         np.int32)
+        _, dist, sweeps, stopped = self._check(occ, seed, goals)
+        assert list(sweeps) == [8, 4, 12, 0]
+        assert list(stopped) == [1, 1, 0, 0]
+        assert dist[0, 0, 8] == 8 and dist[0, 1, 8] == INF
+        assert (dist[3] == INF).all()

@@ -214,6 +214,87 @@ class TestQueueWaitCounters:
         assert svc.stats()["admit_wait_s"] == 0
 
 
+def _load_reader(name):
+    import importlib.util
+    import pathlib
+
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench" /
+            "layer_metrics" / f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"lm_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+class TestRouteCounters:
+    """`layout_stage` adds the Pallas wavefront's counters: sweeps run
+    (`route_wavefront_iters`), grid-slots with a live net
+    (`route_wavefronts`) and wavefronts stopped on their targets
+    (`route_goal_stops`); nothing where no kernel routed."""
+
+    @pytest.fixture(scope="class")
+    def distilled(self):
+        from repro.api import DesignSession
+
+        session = DesignSession()
+        req = _request(seed=3, layout=True, requirements=REQS)
+        batch = session.distill_stage(session.explore_stage([req]))
+        return session, batch
+
+    def test_host_engine_adds_no_route_counters(self, distilled):
+        session, batch = distilled
+        session.layout_stage(batch.buckets[0])
+        assert session.stats["layout_dispatches"] >= 1
+        assert "route_wavefront_iters" not in session.stats
+        assert "route_wavefronts" not in session.stats
+
+    def test_kernel_route_counters(self, distilled, monkeypatch):
+        from repro.eda import batched_flow
+
+        session, batch = distilled
+        seen = []
+
+        def layout(specs, *, coarse=64, capacity=4, engine=None):
+            res = batched_flow.generate_layouts(
+                specs, coarse=coarse, capacity=capacity, use_kernel=True)
+            seen.append(res.routing)
+            return res
+
+        monkeypatch.setattr(session, "layout", layout)
+        for bucket in batch.buckets:
+            session.layout_stage(bucket)
+        stats = session.stats
+        live = sum(int((r.routed + r.failed).sum()) for r in seen)
+        assert stats["route_wavefronts"] == live > 0
+        assert stats["route_wavefront_iters"] == sum(
+            int(r.sweeps.sum()) for r in seen)
+        assert stats["route_goal_stops"] == sum(
+            int(r.goal_stops.sum()) for r in seen)
+        assert 0 < stats["route_goal_stops"] <= live
+        read = _load_reader("route_iters_per_wavefront")
+        ctx = {"window": type("W", (), {"stats": dict(stats),
+                                        "traced_stats": None})()}
+        assert read(ctx) == pytest.approx(
+            stats["route_wavefront_iters"] / live)
+
+    def test_route_iters_reader(self):
+        from types import SimpleNamespace
+
+        read = _load_reader("route_iters_per_wavefront")
+
+        def ctx(stats, traced=None):
+            return {"window": SimpleNamespace(stats=stats,
+                                              traced_stats=traced)}
+
+        stats = {"route_wavefronts": 40, "route_wavefront_iters": 6000}
+        assert read(ctx(stats)) == pytest.approx(150.0)
+        traced = {"route_wavefronts": 10, "route_wavefront_iters": 1200}
+        assert read(ctx(stats, traced)) == pytest.approx(120.0)
+        # a program without the counters, or no wavefront: nothing
+        assert read(ctx({"layout_dispatches": 3})) is None
+        assert read(ctx({**stats, "route_wavefronts": 0})) is None
+
+
 class TestTraceSpan:
     def test_recorded_edges_and_delegation(self):
         rec = SpanRecorder()

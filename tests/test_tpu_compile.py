@@ -8,8 +8,10 @@ only the test worker that runs this file loads the TPU library.
 
 Shapes are the real ones: the wavefront grids of the paper sweep's
 layout buckets (64 kb arrays lay out as tall (2142, 19) or wide
-(122, 1090) routing grids), the routing program of the largest 64 kb
-bucket, and the rank kernels at the service's population and at 4096.
+(122, 1090) routing grids), with and without the router's target
+cells, the routing program of the largest 64 kb bucket (which takes the
+kernel with its targets), and the rank kernels at the service's
+population and at 4096.
 """
 import os
 
@@ -20,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.eda import batched_flow
 from repro.kernels.acim_matmul.kernel import acim_matmul_kernel
-from repro.kernels.maze_route.kernel import wavefront_kernel
+from repro.kernels.maze_route.kernel import (goal_wavefront_kernel,
+                                             wavefront_kernel)
 from repro.kernels.pareto_dom.kernel import (dominance_matrix_kernel,
                                              nds_rank_kernel)
 
@@ -66,6 +69,15 @@ def _compile(lowered) -> str:
 def test_wavefront_kernel_compiles(one_chip, shape):
     grid = _spec(one_chip, shape, jnp.int8)
     _compile(wavefront_kernel.lower(grid, grid))
+
+
+@pytest.mark.parametrize("shape", [(8, 344, 128), (8, 2144, 128),
+                                   (1, 128, 1152), (32, 1032, 1024)])
+def test_goal_wavefront_kernel_compiles(one_chip, shape):
+    # the router's form: two target cells per grid, by scalar prefetch
+    grid = _spec(one_chip, shape, jnp.int8)
+    goals = _spec(one_chip, (shape[0], 2, 2), jnp.int32)
+    _compile(goal_wavefront_kernel.lower(grid, grid, goals))
 
 
 def test_route_program_compiles_with_kernel(one_chip, monkeypatch):
