@@ -8,8 +8,6 @@ Sections:
   fig10  — EE/area span + SOTA comparison
   table2 — flow wall-clock comparison
   snr_mc — Monte-Carlo SNR vs analytical model (Eqs. 2-6)
-  kernels— Pallas kernel microbenchmarks (CPU interpret timings)
-  roofline — dry-run roofline table (if runs/dryrun is populated)
 """
 from __future__ import annotations
 
@@ -55,21 +53,6 @@ def main() -> None:
         from benchmarks import snr_mc
 
         snr_mc.main()
-
-    if "kernels" not in skip:
-        _section("kernel_microbench")
-        from benchmarks import kernels as kb
-
-        kb.main()
-
-    if "roofline" not in skip:
-        _section("roofline (from runs/dryrun)")
-        try:
-            from benchmarks import roofline
-
-            roofline.main()
-        except Exception as e:  # noqa: BLE001
-            print(f"roofline unavailable: {e}")
 
 
 if __name__ == "__main__":

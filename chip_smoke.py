@@ -98,7 +98,7 @@ def require_tpu():
 def sweep_requests():
     from repro.api import DesignRequest, Requirements
 
-    # the paper-sweep requirements of benchmarks/service_bench.py
+    # the paper-sweep requirements of bench/configs/paper_layout.json
     reqs = Requirements(min_tops=0.5, min_snr_db=15.0)
     laid = [DesignRequest(array_size=s, seed=sd, pop_size=POP,
                           generations=GENS, requirements=reqs, layout=True)

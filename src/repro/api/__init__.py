@@ -18,8 +18,6 @@ optionally a persistent, cross-process `ArtifactCache`
 multi-tenant layer (request coalescing, grid-shape layout bucketing,
 and the thread-pumped `serve()` loop with latency-bounded coalescing
 windows).
-The legacy entry points (`repro.core.explorer.explore` and friends)
-survive as thin deprecation shims over this package.
 """
 from repro.api.request import DesignRequest, Requirements
 from repro.api.session import (BucketResult, DesignArtifact, DesignSession,

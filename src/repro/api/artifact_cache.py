@@ -9,7 +9,7 @@ serves a repeat request with **zero** explorer dispatches
 (`tests/test_design_service_async.py` asserts this through a real
 subprocess).
 
-Layout (documented in `docs/benchmarks.md`):
+Layout (documented in `docs/api.md`):
 
     <root>/<request.sha()>.json     one complete DesignArtifact dump
 

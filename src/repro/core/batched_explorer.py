@@ -1,23 +1,20 @@
 """Batched one-compile MOGA explorer: a vmapped multi-cell NSGA-II sweep.
 
-The paper's headline claim is *agile* design-space exploration; the
-sequential `explore_sizes` loop undercut it by re-dispatching (and, in the
-seed implementation, re-compiling) the whole NSGA-II program per array
-size.  Here the full (array_size x seed) sweep is ONE compilation and ONE
+The paper's headline claim is *agile* design-space exploration; a
+sequential per-size loop undercuts it by re-dispatching the whole NSGA-II
+program per array size.  Here the full (array_size x seed) sweep is ONE compilation and ONE
 device program: every per-cell quantity (array size, gene bounds,
 calibration constants) is a traced operand (`nsga2.SpaceOperands`), so
 `nsga2.run_cell` is `jax.vmap`-ed over a stacked operand tree and the
 generation loop scans over the whole population stack at once.
 
-`explore()` / `explore_sizes()` in `repro.core.explorer` are thin wrappers
-over `explore_batch`; `nsga2.run` remains the non-vmapped sequential
-reference, and the batched sweep returns bit-identical per-cell fronts
+`repro.api.DesignSession` drives the sweep through `explore_cells`;
+`nsga2.run` remains the non-vmapped sequential reference, and the batched sweep returns bit-identical per-cell fronts
 (same RNG stream, same generation program, mapped).
 
 Trace accounting: compiling the sweep bumps `nsga2.TRACE_COUNTS
 ["run_cell"]` exactly once per program signature — asserted by
-`tests/test_batched_explorer.py` and recorded by
-`benchmarks/explorer_bench.py`.
+`tests/test_batched_explorer.py`.
 """
 from __future__ import annotations
 
@@ -75,8 +72,8 @@ def explore_cells(cells, *, pop_size: int = 256, generations: int = 80,
     concurrent requests into one cell list) and `explore_batch` (which
     crosses sizes x seeds).  Returns {(array_size, seed): ParetoResult} —
     per-cell deduplicated Pareto fronts, identical to what the sequential
-    per-size path (`nsga2.run` + the legacy `explorer.explore`) produces
-    for the same cell.
+    per-size path (`nsga2.run` + `explorer.pareto_result_from_population`)
+    produces for the same cell.
 
     `program` optionally injects a pre-built sweep callable
     (keys, spaces) -> (genes, objs) — the session's program cache — and

@@ -1,6 +1,6 @@
 """MOGA-based design-space explorer (paper Sec. 3.2) with agile filtering.
 
-`explore()` runs NSGA-II for a user-given array size and returns a
+The explorer runs NSGA-II for a user-given array size and returns a
 `ParetoResult`: the deduplicated Pareto-frontier set with both raw objective
 values and human-oriented metrics.  `ParetoResult.filter(...)` implements the
 paper's "agile interaction": users prune the frontier with application
@@ -16,18 +16,16 @@ compiled NSGA-II program (`repro.core.nsga2.run_cell`), so a whole
 dispatch.  The per-cell fronts are identical to the sequential
 `nsga2.run` reference path.
 
-Front-end note: the supported way to drive the flow is `repro.api`
+Front-end note: the way to drive the flow is `repro.api`
 (`DesignRequest` / `DesignSession` / the multi-tenant
-`repro.serve.design_service.DesignService`).  `explore()`,
-`explore_sizes()` and `distill_and_layout()` below are deprecation
-shims over it, kept for source compatibility.
+`repro.serve.design_service.DesignService`); this module holds the
+front program and `ParetoResult` they build on.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import json
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -168,74 +166,6 @@ def pareto_result_from_population(array_size: int, genes: np.ndarray,
         metrics = dict(zip(estimator.REPORT_METRICS,
                            np.asarray(report)[:, rows]))
     return ParetoResult(array_size, specs, metrics)
-
-
-def _deprecated(old: str, new: str) -> None:
-    warnings.warn(
-        f"repro.core.explorer.{old} is deprecated; use {new} "
-        f"(see docs/api.md)", DeprecationWarning, stacklevel=3)
-
-
-def explore(array_size: int, *, pop_size: int = 256, generations: int = 80,
-            seed: int = 0, cal: CalibConstants = CAL28,
-            use_pallas_dominance: bool = False,
-            use_pallas_rank: bool = False) -> ParetoResult:
-    """Deprecated shim over `repro.api`: run the MOGA explorer for one
-    array size and return the (undistilled) `ParetoResult`.
-
-    Use `DesignSession().run(DesignRequest(array_size, layout=False))`
-    instead; repeated shim calls share the process-wide default session's
-    program and front caches."""
-    from repro.api import DesignRequest, default_session
-
-    _deprecated("explore", "repro.api.DesignSession.run")
-    req = DesignRequest(array_size=array_size, seed=seed, pop_size=pop_size,
-                        generations=generations, cal=cal,
-                        use_pallas_dominance=use_pallas_dominance,
-                        use_pallas_rank=use_pallas_rank, layout=False)
-    return default_session().run(req).pareto
-
-
-def explore_sizes(sizes=(4096, 16384, 65536), *, seed: int = 0,
-                  **kw) -> dict[int, ParetoResult]:
-    """Deprecated shim over `repro.api`: Fig. 9(a)(b)-style sweep over
-    array sizes, coalesced by a `DesignService` into one compiled
-    program / one dispatch for the whole sweep."""
-    from repro.api import DesignRequest, default_session
-    from repro.serve.design_service import DesignService
-
-    _deprecated("explore_sizes", "repro.serve.design_service.DesignService")
-    sizes = tuple(sizes)
-    svc = DesignService(session=default_session(),
-                        max_coalesce=max(len(sizes), 1))
-    tickets = {int(s): svc.submit(DesignRequest(
-        array_size=int(s), seed=seed, layout=False, **kw)) for s in sizes}
-    arts = svc.run()
-    return {s: arts[tickets[int(s)]].pareto for s in sizes}
-
-
-def distill_and_layout(array_size: int, *, pop_size: int = 256,
-                       generations: int = 80, seed: int = 0,
-                       cal: CalibConstants = CAL28, coarse: int = 64,
-                       capacity: int = 4, use_pallas_dominance: bool = False,
-                       use_pallas_rank: bool = False, **filter_kw):
-    """Deprecated shim over `repro.api`: MOGA sweep -> agile distillation
-    -> batched layout generation (paper Fig. 4 end to end).
-
-    `filter_kw` are `ParetoResult.filter` thresholds (the
-    `repro.api.Requirements` fields).  Returns `(distilled, layouts)`
-    exactly like `DesignSession.run(...)`'s artifact carries them."""
-    from repro.api import DesignRequest, Requirements, default_session
-
-    _deprecated("distill_and_layout", "repro.api.DesignSession.run")
-    req = DesignRequest(array_size=array_size, seed=seed, pop_size=pop_size,
-                        generations=generations, cal=cal,
-                        use_pallas_dominance=use_pallas_dominance,
-                        use_pallas_rank=use_pallas_rank,
-                        requirements=Requirements(**filter_kw),
-                        coarse=coarse, capacity=capacity, layout=True)
-    artifact = default_session().run(req)
-    return artifact.pareto, artifact.layouts
 
 
 def full_design_space(array_size: int, cal: CalibConstants = CAL28):

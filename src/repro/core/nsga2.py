@@ -54,9 +54,8 @@ from repro.core.constants import CAL28, CalibConstants
 Array = jax.Array
 
 # Trace-count probe: incremented (as a Python side effect) every time the
-# generation program body is traced.  `benchmarks/explorer_bench.py` and the
-# batched-explorer tests read deltas of this counter to assert the
-# one-compile sweep contract.
+# generation program body is traced.  The batched-explorer tests read
+# deltas of this counter to assert the one-compile sweep contract.
 TRACE_COUNTS: collections.Counter = collections.Counter()
 
 # Single source of truth for the variation-probability defaults shared by
@@ -316,7 +315,7 @@ def run(cfg: NSGA2Config, key: Array | None = None) -> Population:
     """Full NSGA-II run; returns the final population (feasible by repair).
 
     Sequential single-cell path: one compile serves every array size /
-    calibration (both are operands), so `explore_sizes` re-dispatches the
+    calibration (both are operands), so a per-size loop re-dispatches the
     same executable per size.
     """
     if key is None:

@@ -415,7 +415,7 @@ def _route_program(occ0: Array, nets: NetBatch, *, capacity: int,
 
 @dataclasses.dataclass
 class RouteSchedule:
-    """Trace of the conflict-aware scheduler, for tests and the bench.
+    """Trace of the conflict-aware scheduler, for tests.
 
     dispatches[r] = (spec, slot) lanes whose wavefronts were computed
     together in round r; bboxes is every net's expanded bounding box

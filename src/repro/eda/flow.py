@@ -7,9 +7,9 @@ same vectorized components the batched flow vmaps (`placer.rect_tensors`
 for placement, the `kernels.maze_route` wavefront for routing), plus the
 full named-instance / wire-geometry materialization that only makes
 sense one spec at a time.  To lay out a whole distilled Pareto set, use
-`repro.eda.batched_flow.generate_layouts` (or
-`repro.core.explorer.distill_and_layout`) — one dispatch per stage for
-the entire batch, identical per-spec results.
+`repro.eda.batched_flow.generate_layouts` (or a `repro.api.DesignRequest`
+with `layout=True`) — one dispatch per stage for the entire batch,
+identical per-spec results.
 
 `drc_lite` here is the host sweep-line reference; the batched flow
 vectorizes the same checks as a pairwise-overlap reduction.

@@ -19,7 +19,6 @@ identical to this sequential one.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 import numpy as np
 
@@ -104,22 +103,13 @@ def backtrace(dist: np.ndarray, dst: tuple[int, int]):
 
 def route(placement: Placement, nets: list[tuple[str, list[tuple[int, int]]]],
           *, coarse: int = 64, capacity: int = 4,
-          use_kernel: bool | None = None,
           impl: str | None = None) -> RoutingResult:
     """Route multi-pin nets (star topology around the first pin) on a
     coarse grid.  nets: (name, [(x, y) pin coords in F units]).
 
-    `impl` passes through to `wavefront_distance` — with both it and
-    `use_kernel` unset, host calls get the frontier-bucketed engine
-    (every impl produces the identical field, so the routing result
-    does not depend on the choice)."""
-    if use_kernel is not None:
-        warnings.warn(
-            "route(use_kernel=...) is deprecated; pass "
-            "impl='kernel'/'ref' (see docs/kernels.md)",
-            DeprecationWarning, stacklevel=2)
-        if impl is None:
-            impl = "kernel" if use_kernel else "ref"
+    `impl` passes through to `wavefront_distance` — unset, host calls
+    get the frontier-bucketed engine (every impl produces the identical
+    field, so the routing result does not depend on the choice)."""
     gh, gw = grid_shape(placement.width, placement.height, coarse)
     occ_count = np.zeros((gh, gw), np.int16)
     wires: list[Wire] = []

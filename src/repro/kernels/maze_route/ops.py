@@ -19,9 +19,8 @@ concrete host arrays off-TPU the frontier engine wins — per-level work
 is proportional to the active frontier, not H×W — and is the default;
 it returns numpy (callers on this path, e.g. `repro.eda.router`, read
 the field on host anyway).  Host-only impls raise under tracing rather
-than silently falling back.  ``use_kernel=True/False`` remains as the
-legacy spelling of impl="kernel"/"ref" (tests force the kernel in
-interpret mode off-TPU and assert it matches the ref).
+than silently falling back.  Tests force the kernel in interpret mode
+off-TPU with impl="kernel" and assert it matches the ref.
 
 `goal_wavefront` is the kernel alone, stopped per grid once the given
 goal cells are resolved (the router's targets): the field is then exact
@@ -39,8 +38,6 @@ batch are handled the same way by the caller (`repro.eda.batched_flow`
 blocks every cell beyond a spec's own grid bounds).
 """
 from __future__ import annotations
-
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +83,6 @@ def pad_blocked(occ: jax.Array, seed: jax.Array):
 
 
 def wavefront_distance(occ: jax.Array, seed: jax.Array, *,
-                       use_kernel: bool | None = None,
                        interpret: bool | None = None,
                        impl: str | None = None) -> jax.Array:
     """BFS distance field(s) for the Lee maze router.
@@ -96,17 +92,8 @@ def wavefront_distance(occ: jax.Array, seed: jax.Array, *,
     Host impls ("frontier", "bfs") return numpy arrays; traced/"ref"/
     "kernel" return jax arrays.
     """
-    if use_kernel is not None:
-        warnings.warn(
-            "wavefront_distance(use_kernel=...) is deprecated; pass "
-            "impl='kernel'/'ref' (see docs/kernels.md)",
-            DeprecationWarning, stacklevel=2)
     if impl is None:
-        if use_kernel is True:
-            impl = "kernel"
-        elif use_kernel is False:
-            impl = "ref"
-        elif _traced(occ, seed) or jax.default_backend() == "tpu":
+        if _traced(occ, seed) or jax.default_backend() == "tpu":
             impl = "kernel" if jax.default_backend() == "tpu" else "ref"
         else:
             impl = "frontier"

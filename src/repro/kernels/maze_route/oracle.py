@@ -3,11 +3,11 @@
 `wavefront_distance_bfs` is the slowest, most obviously-correct
 implementation of the wavefront contract: a textbook `collections.deque`
 breadth-first search, one cell at a time, no numpy vectorization and no
-JAX.  It exists so the property suite (`tests/test_maze_route_properties
-.py`) and the routing micro-benchmark (`benchmarks/route_bench.py`) can
-pin every production engine — the jnp sweeping ref, the Pallas Jacobi
-kernel, and the frontier-bucketed numpy engine — against something a
-reviewer can verify by reading thirty lines.
+JAX.  It exists so the property suite
+(`tests/test_maze_route_properties.py`) can pin every production engine
+— the jnp sweeping ref, the Pallas Jacobi kernel, and the
+frontier-bucketed numpy engine — against something a reviewer can
+verify by reading thirty lines.
 
 Semantics (shared by all four implementations, see `ref.py`):
 

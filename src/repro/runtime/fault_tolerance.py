@@ -148,7 +148,7 @@ class StragglerMonitor:
 
 @dataclasses.dataclass
 class FailureInjector:
-    """Deterministic fault schedule for tests and chaos benchmarks.
+    """Deterministic fault schedule for tests.
 
     Two addressing modes:
 

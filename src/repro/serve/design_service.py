@@ -4,12 +4,12 @@ fault-tolerant front door.
 The design-flow counterpart of `repro.serve.engine.ServeEngine`'s slot
 model: concurrent users `submit()` `DesignRequest`s and collect
 ticketed `DesignArtifact`s, while the service amortizes the heavy work
-across tenants.  Two driving modes share one queue:
+across tenants.  Two ways to drive one queue:
 
   * **synchronous drain** — `step()` takes one coalesced batch (up to
-    `max_coalesce` requests), `run()` drains everything.  This is the
-    PR-3 shape and stays the right tool for scripted batch jobs
-    (`explore_sizes`, the benchmarks' cold/warm sweeps).
+    `max_coalesce` requests), `run()` drains everything.  The right
+    tool for scripted batch jobs: a whole sweep submitted, then drained
+    in coalesced batches through `DesignSession.run_many`.
   * **staged pipeline** — `serve()` starts an admission pump with
     latency-bounded coalescing windows (dispatch at `max_coalesce`
     queued OR `coalesce_window_s` past the oldest request) feeding the
@@ -29,11 +29,7 @@ across tenants.  Two driving modes share one queue:
     distill worker submits each bucket the moment it is formed, and
     `layout_workers=K` independent pool workers consume the bucket
     queue concurrently (buckets are independent by construction, so the
-    layout bottleneck parallelizes; on a multi-core host K=4 is the
-    `BENCH_service.json` layout-pool scenario).  `serve(pipelined=
-    False)` falls back to the PR-4 serial pump (one thread, one
-    coalesced batch at a time through `run_many`) for comparison —
-    `benchmarks/service_bench.py` records both.
+    layout bottleneck parallelizes on a multi-core host).
 
 Stage-safety: the `DesignSession` is not thread-safe in general, but
 the stages partition its state — only the explore worker touches the
@@ -113,8 +109,8 @@ the Pallas wavefront routed), and the fault-tolerance counters
 `bucket_retries` / `bucket_failures` / `shed_buckets` / `shed_losses`
 / `stage_worker_restarts` / `preemptions` / `journaled_tickets`) plus
 live pipeline gauges (queue depths, per-stage occupancy and cumulative
-busy time, and the explore/layout overlap clock the benchmark's
-overlap fraction is computed from).
+busy time, and the explore/layout overlap clock that
+`pipeline_overlap_fraction` is computed from).
 
 Telemetry & control (`docs/observability.md`): `stats()` is now the
 thin compatibility view over a typed metrics registry —
@@ -344,7 +340,6 @@ class DesignService:
         self._watchdog_poll_s = 0.02
         self._replayed: set[int] = set()   # tickets resubmitted from the WAL
         self._preempted = False
-        self._pipelined = False
         self._closing = False
         self._pump_error: BaseException | None = None
         # occupancy clocks (under self._lock): refcount + first-busy
@@ -498,7 +493,7 @@ class DesignService:
           * `queue_depth` — submissions not yet admitted to a batch;
           * `inflight_batches` — admitted, not yet finalized;
           * `inflight_buckets` — buckets running in the layout pool;
-          * `done_count`, `pump_alive`, `pipelined`, `layout_workers`,
+          * `done_count`, `pump_alive`, `layout_workers`,
             `preempted`, `replayed_tickets`;
           * `stage_queue_depth` / `stage_busy` / `stage_busy_s` — per
             stage: items waiting, busy right now, cumulative busy time;
@@ -521,7 +516,6 @@ class DesignService:
             snap["inflight_buckets"] = len(self._inflight_buckets)
             snap["done_count"] = len(self.done)
             snap["pump_alive"] = self._pump_alive()
-            snap["pipelined"] = self._pipelined
             snap["layout_workers"] = self.layout_workers
             snap["preempted"] = self._preempted
             snap["replayed_tickets"] = len(self._replayed)
@@ -837,29 +831,17 @@ class DesignService:
             return True
         return any(t.is_alive() for t in self._stage_threads)
 
-    def serve(self, *, pipelined: bool = True) -> "DesignService":
-        """Start the serve pump (idempotent); returns `self` so
+    def serve(self) -> "DesignService":
+        """Start the staged pipeline (idempotent); returns `self` so
         `with DesignService(...).serve() as svc:` reads naturally.
 
-        `pipelined=True` (default) starts the staged pipeline executor:
-        admission pump + explore/distill/finalize workers and the
-        `layout_workers`-wide layout pool, overlapping consecutive
-        batches and streaming layout buckets.  `pipelined=False` is the
-        serial pump (one thread, one coalesced batch at a time through
-        `run_many`) — kept for comparison benchmarks and as a minimal
-        fallback.
-
-        Idempotent for the same mode; asking for the *other* mode while
-        a pump is alive raises (close() first to switch).  If a journal
+        Starts the admission pump, the explore/distill/finalize workers
+        and the `layout_workers`-wide layout pool, overlapping
+        consecutive batches and streaming layout buckets.  If a journal
         holds tickets from a preempted predecessor, they are replayed
         (resubmitted, in order) before this call returns."""
         with self._lock:
             if self._pump_alive():
-                if pipelined != self._pipelined:
-                    mode = "pipelined" if self._pipelined else "serial"
-                    raise RuntimeError(
-                        f"serve(pipelined={pipelined}) while a {mode} pump "
-                        f"is already running; close() first to switch modes")
                 return self
             if self._closing:
                 # a concurrent close() is joining the old pump; starting a
@@ -879,42 +861,40 @@ class DesignService:
                     "requested; a preempted service stays drained — replay "
                     "its journal from a fresh service (fresh guard)")
             self._pump_error = None
-            self._pipelined = pipelined
-            if pipelined:
-                d = self.pipeline_depth
-                self._queues = {"explore": queue.Queue(maxsize=d),
-                                "distill": queue.Queue(maxsize=d),
-                                "layout": queue.Queue(),    # unbounded: pool
-                                "finalize": queue.Queue()}  # retries re-put
-                self._redo = {s: collections.deque() for s in _STAGES}
-                self._layout_live = self.layout_workers
-                self._next_wid = self.layout_workers
-                self._stage_threads = [
-                    threading.Thread(target=self._stage_worker,
-                                     args=("explore", None),
-                                     name="design-service-explore",
-                                     daemon=True),
-                    threading.Thread(target=self._stage_worker,
-                                     args=("distill", None),
-                                     name="design-service-distill",
-                                     daemon=True),
-                    *(threading.Thread(target=self._stage_worker,
-                                       args=("layout", w),
-                                       name=f"design-service-layout-{w}",
-                                       daemon=True)
-                      for w in range(self.layout_workers)),
-                    threading.Thread(target=self._stage_worker,
-                                     args=("finalize", None),
-                                     name="design-service-finalize",
-                                     daemon=True)]
-                for t in self._stage_threads:
-                    t.start()
-                if self._straggler is not None and self.layout_workers > 1:
-                    self._watchdog_stop.clear()
-                    self._watchdog = threading.Thread(
-                        target=self._watchdog_loop,
-                        name="design-service-watchdog", daemon=True)
-                    self._watchdog.start()
+            d = self.pipeline_depth
+            self._queues = {"explore": queue.Queue(maxsize=d),
+                            "distill": queue.Queue(maxsize=d),
+                            "layout": queue.Queue(),    # unbounded: pool
+                            "finalize": queue.Queue()}  # retries re-put
+            self._redo = {s: collections.deque() for s in _STAGES}
+            self._layout_live = self.layout_workers
+            self._next_wid = self.layout_workers
+            self._stage_threads = [
+                threading.Thread(target=self._stage_worker,
+                                 args=("explore", None),
+                                 name="design-service-explore",
+                                 daemon=True),
+                threading.Thread(target=self._stage_worker,
+                                 args=("distill", None),
+                                 name="design-service-distill",
+                                 daemon=True),
+                *(threading.Thread(target=self._stage_worker,
+                                   args=("layout", w),
+                                   name=f"design-service-layout-{w}",
+                                   daemon=True)
+                  for w in range(self.layout_workers)),
+                threading.Thread(target=self._stage_worker,
+                                 args=("finalize", None),
+                                 name="design-service-finalize",
+                                 daemon=True)]
+            for t in self._stage_threads:
+                t.start()
+            if self._straggler is not None and self.layout_workers > 1:
+                self._watchdog_stop.clear()
+                self._watchdog = threading.Thread(
+                    target=self._watchdog_loop,
+                    name="design-service-watchdog", daemon=True)
+                self._watchdog.start()
             self._pump = threading.Thread(target=self._pump_loop,
                                           name="design-service-pump",
                                           daemon=True)
@@ -923,16 +903,14 @@ class DesignService:
         return self
 
     def _pump_loop(self) -> None:
-        """Admission: wait out the coalescing window, then either hand the
-        batch to the explore queue (pipelined) or dispatch it inline
-        (serial).  With a guard attached, waits are bounded so a
-        preemption request is noticed within ~0.1s even on an idle
+        """Admission: wait out the coalescing window, then hand the batch
+        to the explore queue.  With a guard attached, waits are bounded
+        so a preemption request is noticed within ~0.1s even on an idle
         queue."""
-        pipelined = self._pipelined
         caps = []
         if self._guard is not None:
             caps.append(0.1)
-        if self.controller is not None and pipelined:
+        if self.controller is not None:
             # bounded waits guarantee a controller tick at least every
             # tick_interval_s even on an idle queue
             caps.append(self.controller.config.tick_interval_s)
@@ -942,8 +920,7 @@ class DesignService:
                 preempt = False
                 with self._lock:
                     while True:
-                        if pipelined:
-                            self._control_tick()
+                        self._control_tick()
                         if (self._guard is not None and self._guard.preempted
                                 and not self._preempted):
                             preempt = True
@@ -975,20 +952,16 @@ class DesignService:
                 if preempt:
                     self._preempt_drain()
                     return
-                if pipelined:
-                    self._admit_batch()
-                else:
-                    self._dispatch_once()
-        except Exception as e:   # serial path; _dispatch_once restored it
+                self._admit_batch()
+        except Exception as e:   # admission failed: close() restores
             with self._lock:
                 self._pump_error = e
                 self._done_cv.notify_all()
         finally:
-            if pipelined:
-                # one sentinel, forwarded stage to stage (fanned out
-                # across the pool at layout), drains and stops the whole
-                # chain in order
-                self._queues["explore"].put(None)
+            # one sentinel, forwarded stage to stage (fanned out across
+            # the pool at layout), drains and stops the whole chain in
+            # order
+            self._queues["explore"].put(None)
 
     def _admit_batch(self) -> None:
         with self._lock:

@@ -14,9 +14,8 @@ registry.  Three metric kinds:
     live worker count), also callback-backed for the same reason;
   * `Histogram` — fixed log-spaced buckets (`DEFAULT_LATENCY_BUCKETS`:
     powers of two from 1 ms to ~73 min) plus a bounded reservoir of
-    raw samples, so `summary()` reports exact p50/p95/p99 through the
-    *same* `percentile()` the benchmarks use (identical quantile math
-    by construction, not by convention) while the bucket counts stay
+    raw samples, so `summary()` reports exact p50/p95/p99 through
+    this module's `percentile()` while the bucket counts stay
     prometheus-renderable.
 
 Metrics are identified by name + optional label set (e.g.
@@ -27,10 +26,7 @@ for the same (name, labels) twice returns the same object.
 snapshot into prometheus text exposition format.
 
 `percentile()` reimplements numpy's default linear-interpolation
-quantile in pure Python: `benchmarks/service_bench.py` previously
-computed its ticket p50/p95 with `np.percentile` in five separate
-scenarios — both now call this one helper, so bench columns and
-histogram summaries can never disagree on quantile math.
+quantile in pure Python.
 """
 from __future__ import annotations
 
@@ -50,8 +46,8 @@ METRICS_SCHEMA = 1
 # bench runs are mergeable bucket-for-bucket.
 DEFAULT_LATENCY_BUCKETS = tuple(0.001 * 2.0 ** i for i in range(23))
 
-# Bounded sample reservoir per histogram: enough to keep service-bench
-# scale exact (hundreds of tickets) without letting a long-lived fleet
+# Bounded sample reservoir per histogram: enough to keep a benchmark
+# window exact (hundreds of tickets) without letting a long-lived fleet
 # grow memory without bound.  Beyond the cap the reservoir keeps the
 # most recent samples (sliding window), which is the right bias for an
 # operator asking "what is latency like *now*".
@@ -180,7 +176,7 @@ class Histogram:
 
     def summary(self) -> dict:
         """count/sum/min/max plus exact p50/p95/p99 over the retained
-        reservoir — the same `percentile()` the benchmarks call."""
+        reservoir, through `percentile()`."""
         with self._lock:
             xs = list(self._samples)
             count, total = self._count, self._sum

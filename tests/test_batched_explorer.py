@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 
 import front_oracle
+from repro.api import DesignRequest, DesignSession
 from repro.core import batched_explorer, explorer, nsga2, pareto
 from repro.core.batched_explorer import explore_batch, explore_cells
+from repro.serve.design_service import DesignService
 
 SIZES = (4096, 16384, 65536)
 ORACLE_CELLS = ((4096, 0), (4096, 3), (16384, 1), (16384, 2 ** 31 - 2),
@@ -58,12 +60,22 @@ class TestExploreBatch:
                      for sp in out[(s, 0)].specs}
             assert found == _true_front(s), s
 
-    def test_explore_sizes_wrapper_matches_batch(self):
-        by_size = explorer.explore_sizes(SIZES[:2], seed=4, pop_size=48,
-                                         generations=6)
-        out = explore_batch(SIZES[:2], (4,), pop_size=48, generations=6)
-        for s in SIZES[:2]:
-            assert _front_set(by_size[s]) == _front_set(out[(s, 4)])
+    def test_service_sweep_matches_batch(self):
+        """A `DesignService` sweep over sizes is one explorer dispatch
+        and returns the fronts `explore_batch` gives; the request carries
+        the variation probabilities through to the program."""
+        sizes = SIZES[:2]
+        svc = DesignService(max_coalesce=len(sizes))
+        tickets = {s: svc.submit(DesignRequest(
+            array_size=s, seed=4, pop_size=48, generations=6,
+            crossover_prob=0.8, layout=False)) for s in sizes}
+        done = svc.run()
+        assert svc.stats()["explorer_dispatches"] == 1
+        out = explore_batch(sizes, (4,), pop_size=48, generations=6,
+                            crossover_prob=0.8)
+        for s in sizes:
+            assert _front_set(done[tickets[s]].pareto) \
+                == _front_set(out[(s, 4)])
 
     def test_operand_traced_sequential_path_single_trace(self):
         """Sweeping array sizes sequentially also compiles once: the size
@@ -94,9 +106,11 @@ class TestFusedRankPath:
             np.asarray(pareto.crowding_distance(f, ranks_ref)))
 
     def test_explore_with_pallas_rank_matches_default(self):
-        a = explorer.explore(16384, pop_size=64, generations=8, seed=2)
-        b = explorer.explore(16384, pop_size=64, generations=8, seed=2,
-                             use_pallas_rank=True)
+        session = DesignSession()
+        a, b = (session.run(DesignRequest(
+            array_size=16384, pop_size=64, generations=8, seed=2,
+            use_pallas_rank=rank, layout=False)).pareto
+            for rank in (False, True))
         assert _front_set(a) == _front_set(b)
 
 

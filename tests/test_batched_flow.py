@@ -7,6 +7,7 @@ path is the sequential path, vectorized, not an approximation of it.
 import numpy as np
 import pytest
 
+from repro.api import DesignRequest, DesignSession, Requirements
 from repro.core.acim_spec import MacroSpec
 from repro.eda import netlist as nl, router
 from repro.eda.batched_flow import (NetBatch, _Buffered, _bbox_overlap,
@@ -395,13 +396,16 @@ class TestStillValidBound:
 
 
 class TestDistillAndLayout:
-    def test_explore_to_batched_layouts(self):
-        from repro.core.explorer import distill_and_layout
+    """Explore -> agile distillation -> batched layouts through one
+    `DesignRequest`."""
 
+    def test_explore_to_batched_layouts(self):
         # agile distillation thresholds keep the laid-out batch small
-        distilled, layouts = distill_and_layout(
-            4096, pop_size=48, generations=10, seed=0,
-            min_tops=0.5, min_snr_db=10.0)
+        art = DesignSession().run(DesignRequest(
+            array_size=4096, pop_size=48, generations=10, seed=0,
+            requirements=Requirements(min_tops=0.5, min_snr_db=10.0),
+            layout=True))
+        distilled, layouts = art.pareto, art.layouts
         assert len(distilled) == len(layouts) >= 2
         rows = layouts.metrics_rows()
         assert all(r["drc_clean"] for r in rows)
@@ -409,8 +413,7 @@ class TestDistillAndLayout:
             == [s.as_tuple() for s in distilled.specs]
 
     def test_overfiltered_raises(self):
-        from repro.core.explorer import distill_and_layout
-
         with pytest.raises(ValueError):
-            distill_and_layout(4096, pop_size=32, generations=5,
-                               min_tops=1e9)
+            DesignSession().run(DesignRequest(
+                array_size=4096, pop_size=32, generations=5,
+                requirements=Requirements(min_tops=1e9), layout=True))

@@ -1,6 +1,6 @@
 """The unified `repro.api` front-end: request round-trips, session
-caching (zero-retrace contract), service coalescing, artifact equality
-with the legacy path, and the deprecation shims."""
+caching (zero-retrace contract), service coalescing, and artifact
+equality with the pre-API call sequence."""
 import dataclasses
 import json
 
@@ -10,7 +10,7 @@ import pytest
 
 from repro.api.session import ARTIFACT_SCHEMA
 from repro.api import (DesignArtifact, DesignRequest, DesignSession,
-                       Requirements, default_session)
+                       Requirements)
 from repro.core import explorer, nsga2
 from repro.core.batched_explorer import explore_batch
 from repro.eda.batched_flow import generate_layouts
@@ -254,27 +254,3 @@ class TestDesignService:
         assert done[ta].pareto.array_size == 4096
         assert done[tb].pareto.array_size == 16384
         assert svc.collect(ta) is done[ta]
-
-
-class TestDeprecationShims:
-    def test_explore_warns_and_matches_api(self):
-        with pytest.deprecated_call():
-            res = explorer.explore(4096, pop_size=POP, generations=GENS)
-        art = default_session().run(_request(4096, layout=False))
-        assert res.to_rows() == art.pareto.to_rows()
-
-    def test_explore_sizes_warns(self):
-        with pytest.deprecated_call():
-            # crossover_prob/mutation_prob were explore_batch kwargs; the
-            # request type carries them so old call sites keep working
-            out = explorer.explore_sizes((4096, 16384), pop_size=POP,
-                                         generations=GENS,
-                                         crossover_prob=0.8)
-        assert set(out) == {4096, 16384}
-
-    def test_distill_and_layout_warns_and_matches(self):
-        with pytest.deprecated_call():
-            distilled, layouts = explorer.distill_and_layout(
-                4096, pop_size=POP, generations=GENS,
-                min_tops=0.5, min_snr_db=10.0)
-        assert len(distilled) == len(layouts) >= 2

@@ -172,18 +172,14 @@ class TestFailureRestore:
 
     def test_pump_failure_surfaces_and_tickets_survive(self, monkeypatch):
         svc = DesignService(coalesce_window_s=0.02)
-        real_explore = svc.session.explore_stage
 
         def boom(*a, **kw):
             raise RuntimeError("injected pump failure")
 
-        # the SERIAL pump dispatches through run_many, where a stage
-        # exception is a whole-pump failure (the pipelined executor
-        # instead isolates it into error artifacts — see
-        # tests/test_service_faults.py); explore_stage is shared by
-        # run_many and the pipeline, so the later recovery drain works
-        monkeypatch.setattr(svc.session, "explore_stage", boom)
-        svc.serve(pipelined=False)
+        # the admission pump itself fails (a stage exception is isolated
+        # into error artifacts instead — see tests/test_service_faults.py)
+        monkeypatch.setattr(svc, "_admit_batch", boom)
+        svc.serve()
         ticket = svc.submit(_request(layout=False))
         with pytest.raises(RuntimeError, match="pump failed"):
             svc.collect(ticket, timeout=600)
@@ -195,7 +191,7 @@ class TestFailureRestore:
             svc.close()
         # the ticket is back in the queue, pending — not lost
         assert svc.poll(ticket) is None
-        monkeypatch.setattr(svc.session, "explore_stage", real_explore)
+        monkeypatch.undo()
         assert svc.run()[ticket].ok
 
 

@@ -1,4 +1,4 @@
-"""Staged pipeline executor (`DesignService.serve(pipelined=True)`):
+"""Staged pipeline executor (`DesignService.serve()`):
 ticket-for-ticket equality with the sequential stages, bucket
 streaming / overlap gauges, drain-on-close, per-stage failure
 isolation (error artifacts instead of a dead pipeline), preemption
@@ -152,19 +152,6 @@ class TestPipelineLifecycle:
         assert art.provenance.served_from == "artifact_cache"
         assert art.provenance.explorer_dispatches == 0
         assert art.provenance.pipelined
-
-    def test_serial_pump_still_available(self):
-        svc = DesignService(coalesce_window_s=0.05)
-        with svc.serve(pipelined=False):
-            assert svc.serve(pipelined=False) is svc   # same mode: idempotent
-            with pytest.raises(RuntimeError, match="close\\(\\) first"):
-                svc.serve(pipelined=True)   # mode switch under a live pump
-            t = svc.submit(_request(layout=False))
-            art = svc.collect(t, timeout=600)
-        assert art.ok and not art.provenance.pipelined
-        stats = svc.stats()
-        assert not stats["pipelined"]
-        assert stats["pipeline_overlap_s"] == 0.0
 
     def test_pipeline_depth_validation(self):
         with pytest.raises(ValueError, match="pipeline_depth"):

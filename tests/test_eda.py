@@ -65,9 +65,11 @@ class TestFlow:
         assert m["elapsed_s"] < 120
 
     def test_pareto_to_layout_pipeline(self):
-        from repro.core import explorer
+        from repro.api import DesignRequest, DesignSession
 
-        res = explorer.explore(4096, pop_size=64, generations=15, seed=1)
+        res = DesignSession().run(DesignRequest(
+            array_size=4096, pop_size=64, generations=15, seed=1,
+            layout=False)).pareto
         spec = res.filter(min_tops=0.05).specs[0] if len(
             res.filter(min_tops=0.05)) else res.specs[0]
         lr = generate_layout(spec)

@@ -136,14 +136,14 @@ class TestMazeRoute:
     def test_kernel_matches_ref(self, h, w):
         occ, seed = self._random_case(h * 131 + w, h, w)
         np.testing.assert_array_equal(
-            np.asarray(wavefront_distance(occ, seed, use_kernel=True)),
+            np.asarray(wavefront_distance(occ, seed, impl="kernel")),
             np.asarray(wavefront_distance_ref(occ, seed)))
 
     def test_batched_grids(self):
         occ = jax.random.uniform(jax.random.key(0), (4, 11, 19)) < 0.25
         seed = jnp.zeros((4, 11, 19), bool).at[:, 0, 0].set(True)
         np.testing.assert_array_equal(
-            np.asarray(wavefront_distance(occ, seed, use_kernel=True)),
+            np.asarray(wavefront_distance(occ, seed, impl="kernel")),
             np.asarray(wavefront_distance_ref(occ, seed)))
 
     def test_sweeping_fixed_point_is_relaxation_fixed_point(self):
@@ -158,7 +158,7 @@ class TestMazeRoute:
     def test_walled_off_region_unreachable(self):
         occ = jnp.zeros((7, 7), bool).at[:, 3].set(True)
         seed = jnp.zeros((7, 7), bool).at[3, 0].set(True)
-        d = np.asarray(wavefront_distance(occ, seed, use_kernel=True))
+        d = np.asarray(wavefront_distance(occ, seed, impl="kernel"))
         assert (d[:, 4:] == INF).all()          # right of the wall
         assert (d[:, :3] < INF).all()           # left side fully reached
         assert d[3, 0] == 0
@@ -168,12 +168,12 @@ class TestMazeRoute:
         # wavefront still leaves it — matching the old host BFS
         occ = jnp.zeros((4, 6), bool).at[1, 1].set(True)
         seed = jnp.zeros((4, 6), bool).at[1, 1].set(True)
-        d = np.asarray(wavefront_distance(occ, seed, use_kernel=True))
+        d = np.asarray(wavefront_distance(occ, seed, impl="kernel"))
         assert d[1, 1] == 0 and d[1, 2] == 1 and d[0, 1] == 1
 
     def test_multi_source(self):
         occ, seed = self._random_case(21, 12, 18, p_occ=0.2, n_seeds=3)
-        d = np.asarray(wavefront_distance(occ, seed, use_kernel=True))
+        d = np.asarray(wavefront_distance(occ, seed, impl="kernel"))
         np.testing.assert_array_equal(
             d, np.asarray(wavefront_distance_ref(occ, seed)))
         assert (d[np.asarray(seed)] == 0).all()

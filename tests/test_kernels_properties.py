@@ -94,5 +94,5 @@ class TestMazeRouteProperties:
         np.testing.assert_array_equal(ref, oracle)
         krn = np.asarray(wavefront_distance(jnp.asarray(occ),
                                             jnp.asarray(seed),
-                                            use_kernel=True))
+                                            impl="kernel"))
         np.testing.assert_array_equal(krn, oracle)

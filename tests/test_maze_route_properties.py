@@ -194,18 +194,6 @@ class TestDispatchContract:
         assert isinstance(out, np.ndarray)
         np.testing.assert_array_equal(out, wavefront_distance_bfs(occ, seed))
 
-    def test_use_kernel_legacy_spelling(self):
-        occ = np.zeros((6, 9), bool)
-        seed = np.zeros((6, 9), bool)
-        seed[3, 1] = True
-        oracle = wavefront_distance_bfs(occ, seed)
-        with pytest.warns(DeprecationWarning, match="use_kernel"):
-            out_ref = wavefront_distance(occ, seed, use_kernel=False)
-        np.testing.assert_array_equal(np.asarray(out_ref), oracle)
-        with pytest.warns(DeprecationWarning, match="use_kernel"):
-            out_kernel = wavefront_distance(occ, seed, use_kernel=True)
-        np.testing.assert_array_equal(np.asarray(out_kernel), oracle)
-
 
 def _reach(full, y, x):
     """The sweep at which a goal is resolved: its distance if free, one

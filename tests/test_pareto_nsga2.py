@@ -6,7 +6,14 @@ cleanly when hypothesis is not installed)."""
 import jax.numpy as jnp
 import numpy as np
 
+from repro.api import DesignRequest, DesignSession
 from repro.core import explorer, nsga2, pareto
+
+
+def _front(array_size, **kw):
+    """The undistilled front one explore-only `DesignRequest` returns."""
+    return DesignSession().run(DesignRequest(
+        array_size=array_size, layout=False, **kw)).pareto
 
 
 class TestDominance:
@@ -31,7 +38,7 @@ class TestNSGA2:
         true_front_mask = np.asarray(pareto.non_dominated_mask(objs_all))
         true_front = {tuple(g) for g, m in
                       zip(np.asarray(genes), true_front_mask) if m}
-        res = explorer.explore(16384, pop_size=192, generations=60, seed=3)
+        res = _front(16384, pop_size=192, generations=60, seed=3)
         found = {(int(np.log2(s.h)), int(np.log2(s.l)), s.b_adc)
                  for s in res.specs}
         # every found point is truly non-dominated...
@@ -58,7 +65,7 @@ class TestNSGA2:
         assert (fixed[:, 2] >= 1).all()
 
     def test_agile_filter(self):
-        res = explorer.explore(16384, pop_size=96, generations=25, seed=5)
+        res = _front(16384, pop_size=96, generations=25, seed=5)
         filt = res.filter(min_tops=0.5)
         assert all(m >= 0.5 for m in filt.metrics["tops"])
         assert len(filt) <= len(res)

@@ -468,6 +468,63 @@ class TestPreemptionReplay:
             assert done[t].provenance.served_from == "journal_replay"
 
 
+@pytest.fixture(scope="module")
+def chaos(tmp_path_factory):
+    """A layout-bucket kill and a preemption in one run, then a restart.
+    Phase 1: a guarded service whose first layout bucket dies and whose
+    second admission requests preemption drains what it admitted and
+    journals the rest.  Phase 2: a fresh service over the same cache
+    root replays the journal."""
+    reqs = [_request(seed=sd, requirements=REQS, layout=True)
+            for sd in range(3)]
+    ref = DesignSession().run_many(reqs, strict=False)
+    cache = tmp_path_factory.mktemp("chaos-cache")
+    guard = PreemptionGuard()
+    inj = FailureInjector(guard=guard, fail_at={
+        "layout": [0], "admit": [(1, "preempt")]})
+    svc1 = _fast_svc(session=DesignSession(artifact_cache=cache),
+                     max_coalesce=1, layout_workers=2, max_retries=2,
+                     guard=guard, injector=inj)
+    drained = {}
+    with svc1.serve():
+        tickets = [svc1.submit(r) for r in reqs]
+        for r, t in zip(reqs, tickets):
+            try:
+                drained[r] = svc1.collect(t, timeout=600)
+            except PendingTicket:
+                pass            # journaled: the replaying service owns it
+    svc2 = _fast_svc(session=DesignSession(artifact_cache=cache),
+                     max_coalesce=1, layout_workers=2)
+    pending = svc2.journal.replay()    # peek; replay() does not clear
+    tickets2 = svc2.replay_journal()
+    with svc2.serve():
+        replayed = {r: svc2.collect(t, timeout=600)
+                    for r, t in zip(pending, tickets2)}
+    return {"reqs": reqs, "ref": ref, "drained": drained,
+            "replayed": replayed, "stats1": svc1.stats(),
+            "n_replayed": len(tickets2)}
+
+
+class TestChaos:
+    def test_every_ticket_resolves_equal_to_fault_free(self, chaos):
+        # in-flight tickets are journaled too (at-least-once WAL), so a
+        # request can resolve in both phases; both copies must be right
+        for r in chaos["reqs"]:
+            arts = [a for a in (chaos["drained"].get(r),
+                                chaos["replayed"].get(r)) if a is not None]
+            assert arts, f"seed {r.seed} resolved in neither phase"
+            for a in arts:
+                assert a.ok and a.summary() == chaos["ref"][r].summary()
+
+    def test_kill_retried_and_journal_replayed_in_full(self, chaos):
+        s1 = chaos["stats1"]
+        assert s1["preemptions"] == 1
+        assert s1["bucket_retries"] >= 1
+        assert s1["bucket_failures"] == 0
+        assert chaos["n_replayed"] == s1["journaled_tickets"] >= 1
+        assert chaos["drained"]   # the first admitted batch drained
+
+
 # -- straggler shedding in the layout pool ---------------------------------
 
 class TestStragglerShed:
@@ -523,6 +580,28 @@ class TestLayoutPool:
             assert a.ok
             assert a.provenance.worker_id.startswith("layout-")
         assert svc.stats()["layout_workers"] == 4
+
+    def test_pool_retries_killed_bucket_and_matches_sequential(self):
+        # the K-wide pool under a node fault on its first bucket: the
+        # bucket is retried in place, nothing fails, and the artifacts
+        # equal the fault-free sequential run
+        reqs = [_request(array_size=4096, seed=0, requirements=REQS,
+                         layout=True),
+                _request(array_size=16384, seed=1, requirements=REQS,
+                         layout=True)]
+        ref = DesignSession().run_many(reqs, strict=False)
+        inj = FailureInjector(fail_at={"layout": [0]})
+        svc = _fast_svc(max_coalesce=2, coalesce_window_s=0.3,
+                        layout_workers=4, injector=inj, max_retries=2)
+        with svc.serve():
+            tickets = [svc.submit(r) for r in reqs]
+            arts = [svc.collect(t, timeout=600) for t in tickets]
+        for r, a in zip(reqs, arts):
+            assert a.ok and a.summary() == ref[r].summary()
+        stats = svc.stats()
+        assert stats["bucket_retries"] >= 1
+        assert stats["bucket_failures"] == 0
+        assert inj.fired == [("layout", 0, "node")]
 
     def test_knob_validation(self):
         with pytest.raises(ValueError, match="layout_workers"):

@@ -440,6 +440,41 @@ class TestServiceTelemetry:
         control = [s for s in svc.trace().spans if s.cat == "control"]
         assert len(control) >= len(svc.controller.decisions)
 
+    def test_adaptive_window_artifacts_equal_fixed_window(self, tmp_path):
+        # the controller moves when batches dispatch, never what they
+        # compute; the adaptive run's trace, Gantt and metrics export
+        # with their schema stamps
+        reqs = [_request(seed=sd, requirements=REQS, layout=False)
+                for sd in (0, 1, 2)]
+        fixed = DesignService(max_coalesce=4, coalesce_window_s=0.01)
+        with fixed.serve():
+            tickets = [fixed.submit(r) for r in reqs]
+            ref = [fixed.collect(t, timeout=600) for t in tickets]
+        cfg = ControllerConfig(min_window_s=0.01, max_window_s=0.3,
+                               target_batch=4, tick_interval_s=0.02,
+                               window_smoothing=0.0)
+        svc = DesignService(max_coalesce=4, coalesce_window_s=0.01,
+                            telemetry=True, controller=cfg)
+        with svc.serve():
+            tickets = [svc.submit(r) for r in reqs]
+            arts = [svc.collect(t, timeout=600) for t in tickets]
+        assert [a.summary() for a in arts] == [a.summary() for a in ref]
+        updates = svc.stats()["control_window_updates"]
+        assert len(svc.controller.decisions) >= updates >= 1
+        trace = svc.trace()
+        assert trace.spans
+        trace.to_json(tmp_path / "trace.json")
+        back = TraceExport.from_json(tmp_path / "trace.json")
+        assert back.schema == TRACE_SCHEMA and back.to_events()
+        gantt = trace.gantt()
+        assert gantt["schema"] == TRACE_SCHEMA and gantt["batches"]
+        write_metrics_json(svc.metrics(), tmp_path / "metrics.json")
+        snap = load_snapshot(tmp_path / "metrics.json")
+        assert snap["schema"] == METRICS_SCHEMA
+        assert {"design_ticket_latency_seconds",
+                "design_tickets_served_total",
+                "design_coalesce_window_s"} <= set(snap["metrics"])
+
     def test_telemetry_bundle_shares_recorder_with_session(self):
         tel = Telemetry()
         svc = DesignService(telemetry=tel)

@@ -3,6 +3,7 @@ promotion, validation guards on the remote tier, per-tier session
 provenance, and island-request cache keys."""
 import json
 
+import jax
 import pytest
 
 from repro.api import (ArtifactCache, DesignRequest, DesignSession,
@@ -115,6 +116,28 @@ class TestTieredArtifactCache:
         a3 = w2.run(req)   # artifact cache is consulted before the memo
         assert a3.provenance.served_from == "artifact_cache_l1"
         assert w2.stats["artifact_cache_l1_hits"] == 1
+
+    def test_warm_fleet_serves_island_front_from_l2(self, tmp_path):
+        """Fleet workers with private L1s over one shared L2: the cold
+        worker explores an island request on the mesh (ring migration)
+        and writes the shared tier; every warm worker serves it from L2
+        with zero explorer dispatches; every front equals the same
+        request explored on one device."""
+        req = _request(seed=3, islands=2, migrate_every=5)
+        baseline = DesignSession(mesh=1).run(req)
+        workers = [DesignSession(artifact_cache=TieredArtifactCache(
+            tmp_path / f"w{i}", tmp_path / "shared")) for i in range(3)]
+        arts = [w.run(req) for w in workers]
+        assert arts[0].provenance.served_from == "explorer"
+        assert arts[0].provenance.migration_topology == "ring"
+        assert 1 <= arts[0].provenance.mesh_devices <= jax.device_count()
+        assert workers[0].stats["artifact_cache_l2_writes"] == 1
+        for w, art in zip(workers[1:], arts[1:]):
+            assert art.provenance.served_from == "artifact_cache_l2"
+            assert w.stats["explorer_dispatches"] == 0
+            assert w.stats["artifact_cache_l2_hits"] == 1
+        for art in arts:
+            assert art.summary() == baseline.summary()
 
     def test_legacy_single_tier_stamp_unchanged(self, tmp_path):
         req = _request(seed=9)

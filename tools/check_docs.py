@@ -9,7 +9,7 @@ Checks, over README.md, ROADMAP.md and docs/*.md:
      repo directory) — resolve against the repo root.
   3. Inline-code module references starting with `repro.` resolve to a
      module/package under src/.  A trailing attribute segment is
-     allowed (`repro.core.explorer.distill_and_layout` passes because
+     allowed (`repro.core.explorer.front_program` passes because
      `src/repro/core/explorer.py` exists), and so is a CapWord class
      segment followed by one attribute
      (`repro.api.DesignSession.run_many`).
