@@ -685,6 +685,7 @@ class DesignSession:
             self.bump("route_wavefronts",
                       int((routing.routed + routing.failed).sum()))
             self.bump("route_goal_stops", int(routing.goal_stops.sum()))
+            self.bump("route_walk_steps", int(routing.walk_steps.sum()))
         with trace_span("rows", cat="layout", bucket=bucket.key,
                         specs=len(bucket.specs)):
             rows = dict(zip(res.specs, res.metrics_rows()))

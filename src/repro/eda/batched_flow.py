@@ -59,7 +59,7 @@ from repro.eda.placer import (CATEGORIES, CATEGORY_CELL, BatchDims,
                               PlacerGeometry, category_names, dims_for_spec,
                               geometry, layout_operands, rect_tensors)
 from repro.eda.router import NEIGHBORS, grid_shape
-from repro.kernels.maze_route import INF, goal_wavefront, wavefront_distance
+from repro.kernels.maze_route import INF, goal_route, wavefront_distance
 from repro.kernels.maze_route.frontier import (canvas_free, canvas_index,
                                                expand_buckets, strides)
 from repro.telemetry.spans import trace_span
@@ -317,12 +317,14 @@ def _route_step(occ_count: Array, hubs: Array, tgts: Array, tmask: Array,
 
     occ_count: (B, Gh, Gw) int32; hubs (B, 2); tgts (B, 2, 2);
     tmask (B, 2); nmask (B,).  Returns (occ_count', ok, wirelength,
-    stats): stats is the Pallas wavefront's (sweeps, goal_stopped) per
-    grid, or None where the jnp ref computes the field.
+    stats): stats is the Pallas kernel's (sweeps, goal_stopped,
+    walk_steps) per grid, or None where the jnp ref computes the field.
 
     The kernel stops each grid's wavefront once the slot's targets are
-    resolved (`goal_wavefront`): the backtrace reads only cells closer
-    than its target, all final by then.  The ref computes whole fields.
+    resolved and walks their backtraces through the field it holds
+    (`goal_route`): the walk reads only cells closer than its target,
+    all final by then.  The ref computes whole fields and walks them
+    with `_dir_field` and `_trace_one`, the kernel's test oracle.
     """
     _, gh, gw = occ_count.shape
     occ = occ_count >= capacity
@@ -334,17 +336,18 @@ def _route_step(occ_count: Array, hubs: Array, tgts: Array, tmask: Array,
     if use_kernel or (use_kernel is None
                       and jax.default_backend() == "tpu"):
         goals = jnp.where(active[..., None], tgts, -1)
-        dist, sweeps, stopped = goal_wavefront(occ, seed, goals)
-        stats = (sweeps, stopped)
+        inc, d0, sweeps, stopped, steps = goal_route(occ, seed, goals)
+        reach = d0 < INF
+        wl = jnp.where(active & reach, d0 + 1, 0)
+        stats = (sweeps, stopped, steps)
     else:
-        dist, stats = wavefront_distance(occ, seed, impl="ref"), None
-
-    dirf = jax.vmap(_dir_field)(dist)
-    trace = jax.vmap(jax.vmap(_trace_one, in_axes=(None, None, 0, 0)))
-    inc, wl, reach = trace(dist, dirf, tgts, active)
+        dist = wavefront_distance(occ, seed, impl="ref")
+        dirf = jax.vmap(_dir_field)(dist)
+        trace = jax.vmap(jax.vmap(_trace_one, in_axes=(None, None, 0, 0)))
+        inc, wl, reach = trace(dist, dirf, tgts, active)
+        inc, stats = inc.astype(jnp.int32).sum(axis=1), None
     ok = nmask & jnp.all(reach | ~tmask, axis=1)
-    occ_count = occ_count + (inc.astype(jnp.int32).sum(axis=1)
-                             * ok[:, None, None])
+    occ_count = occ_count + inc * ok[:, None, None]
     return occ_count, ok, wl.sum(axis=1) * ok, stats
 
 
@@ -355,8 +358,8 @@ def _route_program(occ0: Array, nets: NetBatch, *, capacity: int,
     axis with the (occupancy, counters) carry — the sequential
     net-by-net data dependence stays, but there is a single dispatch for
     the whole batch instead of one per net.  The last output is the
-    Pallas wavefront's (sweeps, goal stops) per spec, summed over the
-    slots, or None on the ref path."""
+    Pallas kernel's (sweeps, goal stops, walk steps) per spec, summed
+    over the slots, or None on the ref path."""
 
     def step(carry, slot):
         occ, routed, failed, wirelen = carry
@@ -742,10 +745,11 @@ class BatchedRouting(NamedTuple):
     rounds: int = 0             # wavefront dispatch rounds taken
     collisions: int = 0         # buffered routes dropped by a crossing
     schedule: RouteSchedule | None = None
-    # Pallas wavefront counters, summed over the net slots; None where
-    # no kernel ran (the concurrent engine, or the scan over the ref)
+    # Pallas kernel counters, summed over the net slots; None where no
+    # kernel ran (the concurrent engine, or the scan over the ref)
     sweeps: np.ndarray | None = None      # (B,) relaxation sweeps run
     goal_stops: np.ndarray | None = None  # (B,) stopped on their targets
+    walk_steps: np.ndarray | None = None  # (B,) backtrace steps walked
 
     @property
     def success_rate(self) -> np.ndarray:
@@ -796,10 +800,11 @@ def batched_route(nets: NetBatch, widths: np.ndarray, heights: np.ndarray,
     occ, routed, failed, wirelen, stats = jax.device_get(_route_program(
         jnp.asarray(occ0_np), nets, capacity=capacity, use_kernel=use_kernel))
     occ_np = np.where(blocked, 0, occ).astype(np.int32)
-    sweeps, goal_stops = stats if stats is not None else (None, None)
+    sweeps, goal_stops, walk_steps = (stats if stats is not None
+                                      else (None, None, None))
     return BatchedRouting(routed, failed, wirelen, occ_np, grids,
                           "scan", int(nets.nmask.shape[1]), 0, None,
-                          sweeps, goal_stops)
+                          sweeps, goal_stops, walk_steps)
 
 
 # ----------------------------------------------------------------------

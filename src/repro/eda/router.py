@@ -23,11 +23,7 @@ import dataclasses
 import numpy as np
 
 from repro.eda.placer import Placement
-from repro.kernels.maze_route import INF, wavefront_distance
-
-# Backtrace preference order (down, up, right, left) — shared with the
-# batched router so sequential and batched paths pick identical cells.
-NEIGHBORS = ((1, 0), (-1, 0), (0, 1), (0, -1))
+from repro.kernels.maze_route import INF, NEIGHBORS, wavefront_distance
 
 
 @dataclasses.dataclass(frozen=True)

@@ -20,6 +20,10 @@ to the test period), bounded by H * W.  With goals
 (`goal_wavefront_kernel`, a few target cells per grid) it also stops
 once every goal is resolved: the trip count is then the last goal's
 distance, or the largest finite distance when a goal is unreachable.
+The router's form (`goal_route_kernel`) then walks each goal's
+backtrace to the seed through the field it still holds in VMEM, and
+returns the path cells' counts and the goals' entry distances in place
+of the field.
 
 Why stopping on the goals is exact: synchronous unit-weight relaxation
 from distance-0 seeds keeps the invariant that after k sweeps every cell
@@ -30,7 +34,8 @@ least of them is then final, and so is every neighbour at that
 distance.  A backtrace from the goals reads only cells closer than the
 goal and their neighbours one step closer still, all final; cells not
 yet final are `INF` and never match.  Extra sweeps only finalise more
-cells, so checking every few sweeps changes no answer.
+cells, so checking every few sweeps changes no answer.  The in-kernel
+walk is such a backtrace, with the router's `NEIGHBORS` tie-break.
 
 Semantics match `repro.kernels.maze_route.ref.wavefront_distance_ref`
 exactly (seeds pinned to 0 even when occupied; blocked cells never
@@ -46,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.maze_route.ref import INF
+from repro.kernels.maze_route.ref import INF, NEIGHBORS
 
 # Whole grids live in VMEM: about 22 bytes per cell with the pipeline's
 # double buffers, so the default 16 MiB scoped limit stops near 0.7 M
@@ -72,10 +77,131 @@ def _shift(x: jax.Array, dy: int, dx: int) -> jax.Array:
     return x
 
 
-def _kernel(*refs, n_goals: int):
+def _tile(y, x):
+    """The aligned (8, 128) tile of cell (y, x), and the cell's mask in it."""
+    rows = pl.ds(pl.multiple_of(y & ~7, 8), 8)
+    lanes = pl.ds(pl.multiple_of(x & ~127, 128), 128)
+    iy = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    ix = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    return rows, lanes, (iy == (y & 7)) & (ix == (x & 127))
+
+
+def _read(ref, y, x):
+    """ref[0, y, x] of an int32 (1, H, W) VMEM ref, `INF` outside it: one
+    tile load and a masked reduction."""
+    _, h, w = ref.shape
+    inside = (y >= 0) & (y < h) & (x >= 0) & (x < w)
+    rows, lanes, hit = _tile(jnp.clip(y, 0, h - 1), jnp.clip(x, 0, w - 1))
+    v = jnp.sum(jnp.where(hit, ref[0, rows, lanes], 0))
+    return jnp.where(inside, v, INF)
+
+
+def _bump(ref, y, x, n):
+    """ref[0, y, x] += n, for (y, x) inside the (1, H, W) ref."""
+    rows, lanes, hit = _tile(y, x)
+    ref[0, rows, lanes] = ref[0, rows, lanes] + jnp.where(hit, n, 0)
+
+
+def _pointers(dist):
+    """Each cell's backtrace step, packed as (y << 16) + x: the first
+    `NEIGHBORS` entry at distance d - 1.  Arbitrary where no neighbour
+    is at d - 1 (seeds and `INF` cells), which a walk never reads."""
+    iy = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 0)
+    ix = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
+    ptr = jnp.zeros(dist.shape, jnp.int32)
+    for dy, dx in reversed(NEIGHBORS):       # the first entry wins
+        ptr = jnp.where(_shift(dist, -dy, -dx) == dist - 1,
+                        ((iy + dy) << 16) + (ix + dx), ptr)
+    return ptr
+
+
+def _walk(goal_ref, base, n_goals, dist_ref, ptr_ref, inc_ref):
+    """Backtrace every goal of one grid through its field in VMEM; adds
+    each path's cells to inc_ref and returns the goals' d0 and the walk's
+    steps (sum of the reached d0).
+
+    The same path as `repro.eda.batched_flow._trace_one`: a free goal is
+    entered at its distance, a blocked one at its least 4-neighbour + 1
+    by the first `NEIGHBORS` entry at d0 - 1; then each step moves to the
+    first `NEIGHBORS` neighbour one closer, down to the seed.  A trip of
+    the loop loads the (tr, 128) step tile around each walker and moves
+    it to the end of its straight run in that tile (the cells whose step
+    is its own), counting the cells it leaves; the seed is counted at
+    the end.  The goals walk in one loop, so their dependent reads
+    overlap."""
+    _, h, _ = ptr_ref.shape
+    tr = next(t for t in (64, 32, 16, 8) if h % t == 0)
+    inc_ref[0] = jnp.zeros(inc_ref.shape[1:], jnp.int32)
+    d0s, walkers = [], []
+    for k in range(n_goals):
+        ty = goal_ref[base + 2 * k]
+        tx = goal_ref[base + 2 * k + 1]
+        dv = _read(dist_ref, ty, tx)
+        nd = [_read(dist_ref, ty + dy, tx + dx) for dy, dx in NEIGHBORS]
+        m = functools.reduce(jnp.minimum, nd)
+        d0 = jnp.where(dv < INF, dv, jnp.where(m < INF, m + 1, INF))
+        d0 = jnp.where(ty >= 0, d0, INF)
+        run = d0 < INF
+        blocked = run & (dv >= INF)
+        ty, tx = jnp.maximum(ty, 0), jnp.maximum(tx, 0)   # -1: no goal
+        ey, ex = ty, tx
+        for (dy, dx), v in reversed(list(zip(NEIGHBORS, nd))):
+            at = blocked & (v == d0 - 1)
+            ey, ex = jnp.where(at, ty + dy, ey), jnp.where(at, tx + dx, ex)
+        # a blocked goal is left by its entry step; the walk counts the rest
+        _bump(inc_ref, ty, tx, blocked.astype(jnp.int32))
+        d0s.append(d0)
+        walkers.append((ey, ex, jnp.where(run, d0 - blocked.astype(jnp.int32),
+                                           0)))
+    iy = jax.lax.broadcasted_iota(jnp.int32, (tr, 128), 0)
+    ix = jax.lax.broadcasted_iota(jnp.int32, (tr, 128), 1)
+
+    def cond(walkers):
+        return functools.reduce(jnp.maximum, [r for _, _, r in walkers]) > 0
+
+    def body(walkers):
+        # every walker's load first, so their latencies overlap
+        tiles = []
+        for y, x, _ in walkers:
+            rows = pl.ds(pl.multiple_of(y & -tr, tr), tr)
+            lanes = pl.ds(pl.multiple_of(x & -128, 128), 128)
+            tiles.append((rows, lanes, ptr_ref[0, rows, lanes]))
+        nxt = []
+        for (y, x, r), (rows, lanes, tile) in zip(walkers, tiles):
+            y0, x0 = y & -tr, x & -128
+            yo, xo = y - y0, x - x0
+            move = tile - (((y0 + iy) << 16) + (x0 + ix))   # packed steps
+            step = jnp.sum(jnp.where((iy == yo) & (ix == xo), move, 0))
+            vert = (step == 1 << 16) | (step == -(1 << 16))
+            sign = jnp.where(step > 0, 1, -1)
+            # position along the run's direction, and the run's line
+            along = jnp.where(vert, iy, ix) * sign
+            at = jnp.where(vert, yo, xo) * sign
+            line = jnp.where(vert, ix, iy) == jnp.where(vert, xo, yo)
+            turn = line & (along > at) & (move != step)
+            edge = jnp.where(sign > 0, jnp.where(vert, tr, 128), 1)
+            n = jnp.minimum(jnp.min(jnp.where(turn, along, edge)) - at, r)
+            left = line & (along >= at) & (along < at + n)
+            inc_ref[0, rows, lanes] = (inc_ref[0, rows, lanes]
+                                       + left.astype(jnp.int32))
+            nxt.append((y + jnp.where(vert, sign, 0) * n,
+                        x + jnp.where(vert, 0, sign) * n, r - n))
+        return nxt
+
+    walkers = jax.lax.while_loop(cond, body, walkers)
+    for (y, x, _), d0 in zip(walkers, d0s):
+        _bump(inc_ref, y, x, (d0 < INF).astype(jnp.int32))   # the seed
+    steps = sum(jnp.where(d0 < INF, d0, 0) for d0 in d0s)
+    return d0s, steps
+
+
+def _kernel(*refs, n_goals: int, walk: bool):
     # Mosaic cannot carry or reduce i1 planes through the while loop, so
     # the masks stay int32 and the loop counts cells.
-    if n_goals:
+    if walk:
+        goal_ref, occ_ref, seed_ref, inc_ref, stats_ref, dist_ref, ptr_ref = \
+            refs
+    elif n_goals:
         goal_ref, occ_ref, seed_ref, dist_ref, stats_ref = refs
     else:
         occ_ref, seed_ref, dist_ref, stats_ref = refs
@@ -122,34 +248,54 @@ def _kernel(*refs, n_goals: int):
     dist, sweeps, changed, _ = jax.lax.while_loop(
         cond, body, (dist0, jnp.int32(0), jnp.sum(seed), jnp.int32(1)))
     dist_ref[0] = dist
+    d0s, steps = [], jnp.int32(0)
+    if walk:
+        ptr_ref[0] = _pointers(dist)
+        d0s, steps = _walk(goal_ref, base, n_goals, dist_ref, ptr_ref,
+                           inc_ref)
     # row 0: sweeps run; row 1: 1 where the goals stopped the loop while
-    # the field was still changing
+    # the field was still changing; row 2: the walk's steps; row 3: the
+    # goals' d0, lane k for goal k
     row = jax.lax.broadcasted_iota(jnp.int32, stats_ref.shape[1:], 0)
-    stats_ref[0] = jnp.where(row == 0, sweeps,
-                             (changed > 0).astype(jnp.int32))
+    lane = jax.lax.broadcasted_iota(jnp.int32, stats_ref.shape[1:], 1)
+    out = jnp.where(row == 0, sweeps,
+                    jnp.where(row == 1, (changed > 0).astype(jnp.int32),
+                              jnp.where(row == 2, steps, 0)))
+    for k, d0 in enumerate(d0s):
+        out = jnp.where((row == 3) & (lane == k), d0, out)
+    stats_ref[0] = out
 
 
-def _wavefront_call(occ, seed, goals, interpret):
+def _wavefront_call(occ, seed, goals, interpret, walk=False):
     b, h, w = occ.shape
     assert h % 8 == 0 and w % 128 == 0, (h, w)
     n_goals = 0 if goals is None else goals.shape[1]
     plane = pl.BlockSpec((1, h, w), lambda i, *_: (i, 0, 0))
     stats = pl.BlockSpec((1, 8, 128), lambda i, *_: (i, 0, 0))
+    # the walk's field and step planes stay in VMEM; its output is the
+    # path cells' counts in place of the field.  A step packs a cell as
+    # (y << 16) + x; the goals' d0 take one lane each of a stats row.
+    assert not walk or (h < 2 ** 15 and w <= 2 ** 16 and n_goals <= 128)
+    scratch = [pltpu.VMEM((1, h, w), jnp.int32)] * 2 if walk else []
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1 if n_goals else 0, grid=(b,),
-        in_specs=[plane, plane], out_specs=[plane, stats])
+        in_specs=[plane, plane], out_specs=[plane, stats],
+        scratch_shapes=scratch)
     args = (occ.astype(jnp.int8), seed.astype(jnp.int8))
     if n_goals:
         args = (goals.astype(jnp.int32).reshape(-1),) + args
-    dist, st = pl.pallas_call(
-        functools.partial(_kernel, n_goals=n_goals),
+    plane_out, st = pl.pallas_call(
+        functools.partial(_kernel, n_goals=n_goals, walk=walk),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, h, w), jnp.int32),
                    jax.ShapeDtypeStruct((b, 8, 128), jnp.int32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(*args)
-    return dist, st[:, 0, 0], st[:, 1, 0]
+    if walk:
+        return (plane_out, st[:, 3, :n_goals], st[:, 0, 0], st[:, 1, 0],
+                st[:, 2, 0])
+    return plane_out, st[:, 0, 0], st[:, 1, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -173,3 +319,21 @@ def goal_wavefront_kernel(occ: jax.Array, seed: jax.Array, goals: jax.Array,
     where the goals ended the loop before the fixed point.
     """
     return _wavefront_call(occ, seed, goals, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def goal_route_kernel(occ: jax.Array, seed: jax.Array, goals: jax.Array,
+                      *, interpret: bool = False):
+    """`goal_wavefront_kernel`, then the backtrace of every goal through
+    the field it leaves in VMEM.
+
+    goals: (B, K, 2) int32 (y, x) cells per grid; a negative pair is no
+    goal and walks nothing.  Returns (inc, d0, sweeps, goal_stopped,
+    walk_steps): (B, H, W) int32 path cells per cell, summed over the
+    grid's goals (a cell on two goals' paths counts 2); (B, K) int32
+    each goal's entry distance, `INF` where unreachable or no goal (its
+    path has d0 + 1 cells); (B,) sweeps and goal stops as
+    `goal_wavefront_kernel`; (B,) walk steps, the sum of the reached
+    goals' d0.
+    """
+    return _wavefront_call(occ, seed, goals, interpret, walk=True)

@@ -26,7 +26,10 @@ off-TPU with impl="kernel" and assert it matches the ref.
 goal cells are resolved (the router's targets): the field is then exact
 only out to the goals, which is all a backtrace from them reads.  It
 also returns the sweeps each grid ran.  Full-field callers pass no
-goals and use `wavefront_distance`.
+goals and use `wavefront_distance`.  `goal_route` is the same kernel
+with the backtrace from each goal run inside it (the batched router's
+form): it returns each grid's path-cell counts and its goals' entry
+distances instead of the field.
 
 Padding: the kernel needs TPU tile multiples (sublane 8, lane 128).
 `pad_blocked` pads the occupancy with *blocked* cells and the seed with
@@ -44,7 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.maze_route.frontier import wavefront_distance_frontier
-from repro.kernels.maze_route.kernel import (goal_wavefront_kernel,
+from repro.kernels.maze_route.kernel import (goal_route_kernel,
+                                             goal_wavefront_kernel,
                                              wavefront_kernel)
 from repro.kernels.maze_route.oracle import wavefront_distance_bfs
 from repro.kernels.maze_route.ref import INF, wavefront_distance_ref
@@ -142,3 +146,23 @@ def goal_wavefront(occ: jax.Array, seed: jax.Array, goals: jax.Array, *,
     dist, sweeps, stopped = goal_wavefront_kernel(occ_p, seed_p, goals,
                                                   interpret=interpret)
     return dist[:, :h, :w], sweeps, stopped
+
+
+def goal_route(occ: jax.Array, seed: jax.Array, goals: jax.Array, *,
+               interpret: bool | None = None):
+    """`goal_wavefront`, then each goal's backtrace to its grid's seed,
+    walked inside the kernel (`kernel.goal_route_kernel`).
+
+    goals: (B, K, 2) int32 (y, x) cells; a negative pair is no goal.
+    Returns (inc, d0, sweeps, goal_stopped, walk_steps): (B, H, W) int32
+    path cells per cell over the grid's goals; (B, K) int32 entry
+    distances, `INF` where unreachable or no goal; (B,) sweeps and goal
+    stops as `goal_wavefront`; (B,) walk steps, the reached goals' d0
+    summed.
+    """
+    if interpret is None:
+        interpret = _should_interpret()
+    occ_p, seed_p, (h, w) = pad_blocked(jnp.asarray(occ), jnp.asarray(seed))
+    inc, d0, sweeps, stopped, steps = goal_route_kernel(
+        occ_p, seed_p, goals, interpret=interpret)
+    return inc[:, :h, :w], d0, sweeps, stopped, steps

@@ -35,6 +35,12 @@ import jax.numpy as jnp
 # sweep composition adds two INF-capped terms before re-capping).
 INF = 2 ** 29
 
+# Backtrace preference order (down, up, right, left) as (dy, dx): at
+# distance d a path steps to the first neighbour at d - 1 in this order.
+# Shared by every backtrace (`repro.eda.router`, the batched router and
+# the Pallas walk) so all of them pick identical cells.
+NEIGHBORS = ((1, 0), (-1, 0), (0, 1), (0, -1))
+
 
 def relax_once(dist: jax.Array, free: jax.Array) -> jax.Array:
     """One Jacobi wavefront step: (..., H, W) int32 -> same.

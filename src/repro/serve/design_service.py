@@ -104,8 +104,8 @@ realized coalescing factor, the queue-wait sums `admit_wait_s`
 stage), the host seconds `explore_host_s` of each explore dispatch
 outside its blocking fetch (launch plus post-processing, added by the
 session), the routing kernel's `route_wavefront_iters` /
-`route_wavefronts` / `route_goal_stops` (added per layout bucket where
-the Pallas wavefront routed), and the fault-tolerance counters
+`route_wavefronts` / `route_goal_stops` / `route_walk_steps` (added per
+layout bucket where the Pallas kernel routed), and the fault-tolerance counters
 `bucket_retries` / `bucket_failures` / `shed_buckets` / `shed_losses`
 / `stage_worker_restarts` / `preemptions` / `journaled_tickets`) plus
 live pipeline gauges (queue depths, per-stage occupancy and cumulative

@@ -319,6 +319,7 @@ class TestGoalStoppedScan:
             np.testing.assert_array_equal(kern.wirelength, other.wirelength)
             np.testing.assert_array_equal(kern.occ_count, other.occ_count)
         assert ref.sweeps is None and conc.sweeps is None
+        assert ref.walk_steps is None and conc.walk_steps is None
         return kern
 
     @pytest.mark.parametrize("capacity", [4, 1])
@@ -360,6 +361,10 @@ class TestGoalStoppedScan:
         assert list(kern.sweeps) == [8 + 4 + 0 + 16]
         assert list(kern.goal_stops) == [2]
         assert list(kern.routed + kern.failed) == [3]
+        # the walk takes d0 steps per target: 5 + 0 + 14; a path has
+        # d0 + 1 cells
+        assert list(kern.walk_steps) == [19]
+        assert list(kern.wirelength) == [19 + 3]
 
 
 class TestStillValidBound:

@@ -222,7 +222,7 @@ class TestKernelChoiceOnTPU:
         def route_program(occ0, nets, *, capacity, use_kernel):
             calls.append(use_kernel)
             zeros = jnp.zeros(occ0.shape[0], jnp.int32)
-            return occ0, zeros, zeros, zeros, (zeros, zeros)
+            return occ0, zeros, zeros, zeros, (zeros, zeros, zeros)
 
         monkeypatch.setattr(batched_flow, "_route_program", route_program)
         b, n = 2, 3

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.maze_route import (INF, goal_wavefront, wavefront_distance,
+from repro.kernels.maze_route import (INF, goal_route, goal_wavefront,
+                                      wavefront_distance,
                                       wavefront_distance_bfs)
 from repro.kernels.maze_route.kernel import SWEEPS_PER_CHECK
 from repro.kernels.maze_route.ops import HOST_IMPLS, IMPLS
@@ -303,3 +304,107 @@ class TestGoalStop:
         assert list(stopped) == [1, 1, 0, 0]
         assert dist[0, 0, 8] == 8 and dist[0, 1, 8] == INF
         assert (dist[3] == INF).all()
+
+
+def _walked(occ, seed, goals):
+    """The oracle of `goal_route`: the goal-stopped field of
+    `goal_wavefront`, walked by the batched router's ref path
+    (`_dir_field`, `_trace_one`).  Returns (inc, d0, sweeps, stopped)."""
+    from repro.eda.batched_flow import _dir_field, _trace_one
+
+    dist, sweeps, stopped = goal_wavefront(occ, seed, goals)
+    live = jnp.asarray(goals[..., 0] >= 0)
+    trace = jax.vmap(jax.vmap(_trace_one, in_axes=(None, None, 0, 0)))
+    inc, wl, reach = trace(dist, jax.vmap(_dir_field)(dist),
+                           jnp.asarray(goals), live)
+    d0 = np.where(np.asarray(live & reach), np.asarray(wl) - 1, INF)
+    return (np.asarray(inc).astype(np.int32).sum(axis=1), d0,
+            np.asarray(sweeps), np.asarray(stopped))
+
+
+class TestGoalRoute:
+    """`goal_route` walks each goal's backtrace inside the kernel, through
+    the field its goal-stopped wavefront leaves in VMEM.  Path counts,
+    entry distances, sweeps and stops must equal the goal-stopped field
+    walked by `_dir_field` and `_trace_one`, and the walk's steps must
+    be the reached goals' d0 summed."""
+
+    def _check(self, occ, seed, goals):
+        goals = np.asarray(goals, np.int32)
+        inc, d0, sweeps, stopped, steps = (
+            np.asarray(a) for a in goal_route(occ, seed, goals))
+        want_inc, want_d0, want_sweeps, want_stopped = _walked(occ, seed,
+                                                               goals)
+        np.testing.assert_array_equal(inc, want_inc)
+        np.testing.assert_array_equal(d0, want_d0)
+        np.testing.assert_array_equal(sweeps, want_sweeps)
+        np.testing.assert_array_equal(stopped, want_stopped)
+        np.testing.assert_array_equal(
+            steps, np.where(d0 < INF, d0, 0).sum(axis=1))
+        # a path has d0 + 1 cells
+        np.testing.assert_array_equal(
+            inc.sum(axis=(1, 2)), np.where(d0 < INF, d0 + 1, 0).sum(axis=1))
+        return inc, d0, steps
+
+    @pytest.mark.parametrize("case", range(6))
+    def test_randomized_goals(self, case):
+        rng = np.random.default_rng(3000 + case)
+        b, h, w = 3, int(rng.integers(3, 18)), int(rng.integers(3, 26))
+        occ = rng.random((b, h, w)) < float(rng.uniform(0.0, 0.4))
+        seed = np.zeros((b, h, w), bool)
+        goals = np.full((b, 2, 2), -1, np.int32)
+        for i in range(b):
+            seed[i, rng.integers(h), rng.integers(w)] = True
+            for k in range(2):
+                if k == 0 or rng.random() < 0.7:
+                    goals[i, k] = rng.integers(h), rng.integers(w)
+        self._check(occ, seed, goals)
+
+    def test_blocked_and_unreachable_goals(self):
+        occ = np.zeros((2, 7, 9), bool)
+        occ[0, 3, 5] = True                 # grid 0: a blocked goal
+        occ[0, 2, 5] = occ[0, 4, 5] = True  # ...entered from the side
+        occ[1, :, 4] = True                 # grid 1: a full wall
+        seed = np.zeros_like(occ)
+        seed[:, 3, 0] = True
+        goals = [[[3, 5], [6, 8]],
+                 [[3, 7], [3, 2]]]          # beyond the wall; reached
+        inc, d0, steps = self._check(occ, seed, goals)
+        assert d0[0].tolist() == [5, 11] and inc[0, 3, 5] == 1
+        assert inc[0, 3, 4] == 2            # the entry, on both paths
+        assert d0[1].tolist() == [INF, 2] and list(steps) == [16, 2]
+
+    def test_hub_on_its_own_goal_and_no_goal(self):
+        occ = np.zeros((2, 4, 6), bool)
+        seed = np.zeros_like(occ)
+        seed[:, 1, 2] = True
+        goals = [[[1, 2], [-1, -1]],        # d0 0: the hub alone
+                 [[-1, -1], [-1, -1]]]      # walks nothing
+        inc, d0, steps = self._check(occ, seed, goals)
+        assert d0.tolist() == [[0, INF], [INF, INF]]
+        assert inc[0, 1, 2] == 1 and inc[0].sum() == 1
+        assert (inc[1] == 0).all() and list(steps) == [0, 0]
+
+    def test_shared_paths_count_twice(self):
+        # one corridor: both targets walk left along row 0 to the hub
+        occ = np.zeros((1, 2, 10), bool)
+        occ[0, 1, :] = True
+        seed = np.zeros_like(occ)
+        seed[0, 0, 0] = True
+        inc, d0, steps = self._check(occ, seed, [[[0, 5], [0, 9]]])
+        assert inc[0, 0].tolist() == [2] * 6 + [1] * 4
+        assert d0[0].tolist() == [5, 9] and list(steps) == [14]
+
+    @pytest.mark.parametrize("shape", [(8, 128), (9, 129), (15, 250)])
+    def test_goals_at_the_pad_boundary(self, shape):
+        # goals on the grid's last row and lane, where the kernel's tile
+        # reads cross into the blocked pad
+        h, w = shape
+        occ = np.zeros((2, h, w), bool)
+        occ[1, h - 1, w - 1] = True                   # a blocked corner
+        seed = np.zeros_like(occ)
+        seed[:, 0, w // 2] = True
+        goals = [[[h - 1, w - 1], [h - 1, 0]],
+                 [[h - 1, w - 1], [0, w - 1]]]
+        _, d0, _ = self._check(occ, seed, goals)
+        assert (d0 < INF).all()

@@ -229,8 +229,9 @@ def _load_reader(name):
 class TestRouteCounters:
     """`layout_stage` adds the Pallas wavefront's counters: sweeps run
     (`route_wavefront_iters`), grid-slots with a live net
-    (`route_wavefronts`) and wavefronts stopped on their targets
-    (`route_goal_stops`); nothing where no kernel routed."""
+    (`route_wavefronts`), wavefronts stopped on their targets
+    (`route_goal_stops`) and backtrace steps walked in the kernel
+    (`route_walk_steps`); nothing where no kernel routed."""
 
     @pytest.fixture(scope="class")
     def distilled(self):
@@ -247,6 +248,7 @@ class TestRouteCounters:
         assert session.stats["layout_dispatches"] >= 1
         assert "route_wavefront_iters" not in session.stats
         assert "route_wavefronts" not in session.stats
+        assert "route_walk_steps" not in session.stats
 
     def test_kernel_route_counters(self, distilled, monkeypatch):
         from repro.eda import batched_flow
@@ -271,11 +273,15 @@ class TestRouteCounters:
         assert stats["route_goal_stops"] == sum(
             int(r.goal_stops.sum()) for r in seen)
         assert 0 < stats["route_goal_stops"] <= live
+        assert stats["route_walk_steps"] == sum(
+            int(r.walk_steps.sum()) for r in seen) > 0
         read = _load_reader("route_iters_per_wavefront")
         ctx = {"window": type("W", (), {"stats": dict(stats),
                                         "traced_stats": None})()}
         assert read(ctx) == pytest.approx(
             stats["route_wavefront_iters"] / live)
+        read = _load_reader("route_walk_steps_per_wavefront")
+        assert read(ctx) == pytest.approx(stats["route_walk_steps"] / live)
 
     def test_route_iters_reader(self):
         from types import SimpleNamespace
@@ -292,6 +298,25 @@ class TestRouteCounters:
         assert read(ctx(stats, traced)) == pytest.approx(120.0)
         # a program without the counters, or no wavefront: nothing
         assert read(ctx({"layout_dispatches": 3})) is None
+        assert read(ctx({**stats, "route_wavefronts": 0})) is None
+
+    def test_route_walk_steps_reader(self):
+        from types import SimpleNamespace
+
+        read = _load_reader("route_walk_steps_per_wavefront")
+
+        def ctx(stats, traced=None):
+            return {"window": SimpleNamespace(stats=stats,
+                                              traced_stats=traced)}
+
+        stats = {"route_wavefronts": 40, "route_walk_steps": 4000}
+        assert read(ctx(stats)) == pytest.approx(100.0)
+        traced = {"route_wavefronts": 10, "route_walk_steps": 900}
+        assert read(ctx(stats, traced)) == pytest.approx(90.0)
+        # a program without the counter (the walk outside the kernel),
+        # or no wavefront: nothing
+        assert read(ctx({"route_wavefronts": 40,
+                         "route_wavefront_iters": 6000})) is None
         assert read(ctx({**stats, "route_wavefronts": 0})) is None
 
 

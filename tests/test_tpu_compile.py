@@ -14,6 +14,7 @@ kernel with its targets), and the rank kernels at the service's
 population and at 4096.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.eda import batched_flow
 from repro.kernels.acim_matmul.kernel import acim_matmul_kernel
-from repro.kernels.maze_route.kernel import (goal_wavefront_kernel,
+from repro.kernels.maze_route.kernel import (goal_route_kernel,
+                                             goal_wavefront_kernel,
                                              wavefront_kernel)
 from repro.kernels.pareto_dom.kernel import (dominance_matrix_kernel,
                                              nds_rank_kernel)
@@ -71,13 +73,25 @@ def test_wavefront_kernel_compiles(one_chip, shape):
     _compile(wavefront_kernel.lower(grid, grid))
 
 
-@pytest.mark.parametrize("shape", [(8, 344, 128), (8, 2144, 128),
-                                   (1, 128, 1152), (32, 1032, 1024)])
+GOAL_SHAPES = [(8, 344, 128), (8, 2144, 128), (1, 128, 1152),
+               (32, 1032, 1024)]
+
+
+@pytest.mark.parametrize("shape", GOAL_SHAPES)
 def test_goal_wavefront_kernel_compiles(one_chip, shape):
-    # the router's form: two target cells per grid, by scalar prefetch
+    # two target cells per grid, by scalar prefetch
     grid = _spec(one_chip, shape, jnp.int8)
     goals = _spec(one_chip, (shape[0], 2, 2), jnp.int32)
     _compile(goal_wavefront_kernel.lower(grid, grid, goals))
+
+
+@pytest.mark.parametrize("shape", GOAL_SHAPES)
+def test_goal_route_kernel_compiles(one_chip, shape):
+    # the router's form: the goal wavefront, then the targets' backtraces
+    # walked in the kernel through the field it holds in VMEM
+    grid = _spec(one_chip, shape, jnp.int8)
+    goals = _spec(one_chip, (shape[0], 2, 2), jnp.int32)
+    _compile(goal_route_kernel.lower(grid, grid, goals))
 
 
 def test_route_program_compiles_with_kernel(one_chip, monkeypatch):
@@ -91,8 +105,11 @@ def test_route_program_compiles_with_kernel(one_chip, monkeypatch):
         _spec(one_chip, (b, n, 2), jnp.bool_),
         _spec(one_chip, (b, n), jnp.bool_))
     occ0 = _spec(one_chip, (b, gh, gw), jnp.int32)
-    _compile(batched_flow._route_program.lower(occ0, nets, capacity=4,
-                                               use_kernel=None))
+    text = _compile(batched_flow._route_program.lower(occ0, nets, capacity=4,
+                                                      use_kernel=None))
+    # the backtrace runs inside the kernel: the only XLA loop left is
+    # the scan over net slots, not the walk's nested while loops
+    assert len(re.findall(r"= .*\bwhile\(", text)) == 1
 
 
 @pytest.mark.parametrize("p", [256, 4096])
